@@ -556,13 +556,15 @@ def main(argv=None) -> None:
     from .common import emit
 
     print("name,us_per_call,derived")
+    failed = []
     for mod in (fig6_so2dr_vs_resreu, fig7_breakdown, fig7_codec_breakdown,
                 fig5_config_sweep, fig8_single_step, fig9_incore_vs_oocore,
                 autotune_bench, kernel_micro):
         try:
             emit(mod.run())
-        except Exception as e:  # keep the harness robust
+        except Exception as e:  # report every module, then fail the run
             print(f"{mod.__name__},0,ERROR {e}", file=sys.stdout)
+            failed.append(mod.__name__)
     try:
         rows = roofline.run()
         if rows:
@@ -572,7 +574,13 @@ def main(argv=None) -> None:
                   "(run: PYTHONPATH=src python -m repro.launch.dryrun --all)")
     except Exception as e:
         print(f"roofline,0,ERROR {e}")
+        failed.append(roofline.__name__)
+    if failed:
+        sys.exit(f"benchmark modules raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     main()

@@ -11,7 +11,8 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import numpy as np
 import jax.numpy as jnp
 
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core.distributed import collective_bytes_per_round, run_distributed
 from repro.core.reference import run_reference
 from repro.core.stencil import get_stencil

@@ -35,6 +35,7 @@ class Hardware:
     c_vmem: int = 0       # on-chip scratch (VMEM/shared mem), bytes; 0 = unmodeled
     t_ici_latency: float = 0.0  # per collective phase launch overhead, s
     c_dev: int = 0        # per-device working-set budget, bytes; 0 = c_dmem
+    device_kind: str = ""  # jax Device.device_kind these constants describe
 
     def __post_init__(self):
         # the hierarchical planner budgets a shard's resident working set
@@ -64,8 +65,9 @@ TPU_V5E = Hardware(
     peak_vpu_flops=3.9e12,   # fp32 vector peak (8 lanes*128 sublanes-ish * 2 * clock)
     peak_mxu_flops=197.0e12,  # bf16 MXU peak (assignment constant)
     bw_ici=50.0e9,           # per ICI link (assignment constant)
-    c_vmem=128 * 1024**2,    # v5e VMEM per core
+    c_vmem=128 * 1024**2,    # v5e VMEM per core (kernels request less)
     t_ici_latency=1e-5,      # collective launch overhead per exchange phase
+    device_kind="TPU v5 lite",
 )
 
 

@@ -36,9 +36,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax import make_mesh, shard_map
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from ..compat import AxisType, make_mesh, shard_map
 from .lower import check_domain
 from .stencil import Stencil, get_stencil
 
@@ -192,7 +192,9 @@ def execute_sharded_plan(plan, x, mesh=None, row_axis: str = "data",
             f"mesh shape {shape} does not match plan mesh {plan.mesh_shape}")
     fn = distributed_stencil_step_fn(plan.stencil, plan.k_ici, plan.n,
                                      mesh, row_axis, col_axis)
-    return fn(jnp.asarray(x))
+    # each device receives only its own block: a host-to-one-device copy
+    # would put the whole global domain on the first device
+    return fn(jax.device_put(x, NamedSharding(mesh, P(row_axis, col_axis))))
 
 
 def collective_bytes_per_round(

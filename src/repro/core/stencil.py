@@ -17,12 +17,15 @@ The oracle in :mod:`repro.core.reference` and every out-of-core engine in
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict
+from typing import Callable, Dict, Sequence
 
 import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["Stencil", "get_stencil", "REGISTRY", "box_coeffs"]
+
+# neighbour accessor: spatial offset -> neighbours aligned with the centre
+Neighbours = Callable[[Sequence[int]], jnp.ndarray]
 
 
 def box_coeffs(radius: int) -> np.ndarray:
@@ -52,9 +55,12 @@ def star_coeffs(radius: int) -> np.ndarray:
 class Stencil:
     """An N-D stencil template (``ndim`` trailing spatial axes).
 
-    ``step_valid`` maps an array to its "valid" region — every spatial
-    extent shrinks by ``2r`` — the kernel-level primitive everything else
-    is built from.
+    The update is written once, against a neighbour accessor ``at``:
+    ``at(offset)`` returns the array of neighbours at ``offset`` (one
+    integer per spatial axis), aligned with the cells being updated.
+    :meth:`step_valid` feeds it slices of the input (the valid region,
+    every spatial extent shrinking by ``2r``); the Pallas kernels feed it
+    whole-tile shifts (:meth:`step_shifted`).
     """
 
     name: str
@@ -62,56 +68,68 @@ class Stencil:
     kind: str                    # "box" | "star" | "gradient" | "heat"
     flops_per_elem: int          # arithmetic intensity (paper Table III)
     points: int                  # taps read per output element
-    _step_valid: Callable[[jnp.ndarray], jnp.ndarray]
+    _update: Callable[[Neighbours], jnp.ndarray]
     coeffs: np.ndarray | None = None   # (2r+1, 2r+1) for linear 2-D stencils
     ndim: int = 2                # spatial rank of the template
 
     def step_valid(self, x: jnp.ndarray) -> jnp.ndarray:
         """One time step on the valid interior: every spatial extent
         shrinks by ``2r`` (e.g. ``(H, W) -> (H-2r, W-2r)``)."""
-        return self._step_valid(x)
+        r = self.radius
+        shape = x.shape[x.ndim - self.ndim:]
+
+        def at(offset):
+            return x[(Ellipsis,) + tuple(
+                slice(r + o, s - r + o) for o, s in zip(offset, shape))]
+
+        return self._update(at)
+
+    def step_shifted(self, at: Neighbours) -> jnp.ndarray:
+        """One time step given a caller-supplied neighbour accessor (the
+        output has the shape of what ``at`` returns)."""
+        return self._update(at)
 
     @property
     def is_linear(self) -> bool:
         return self.coeffs is not None
 
 
-def _linear_step(coeffs: np.ndarray) -> Callable[[jnp.ndarray], jnp.ndarray]:
+def _linear_update(coeffs: np.ndarray) -> Callable[[Neighbours], jnp.ndarray]:
     n = coeffs.shape[0]
+    r = n // 2
     taps = [
-        (dy, dx, float(coeffs[dy, dx]))
+        (dy - r, dx - r, float(coeffs[dy, dx]))
         for dy in range(n)
         for dx in range(n)
         if coeffs[dy, dx] != 0.0
     ]
 
-    def step(x: jnp.ndarray) -> jnp.ndarray:
-        h, w = x.shape[-2], x.shape[-1]
+    def update(at: Neighbours) -> jnp.ndarray:
         acc = None
         for dy, dx, c in taps:
-            sl = x[..., dy : h - (n - 1) + dy, dx : w - (n - 1) + dx]
-            term = jnp.asarray(c, x.dtype) * sl
+            sl = at((dy, dx))
+            term = jnp.asarray(c, sl.dtype) * sl
             acc = term if acc is None else acc + term
         return acc
 
-    return step
+    return update
 
 
-def _gradient_step(x: jnp.ndarray) -> jnp.ndarray:
+def _gradient_update(at: Neighbours) -> jnp.ndarray:
     """5-point nonlinear gradient stencil (19 FLOPs/element).
 
     c + dt * (gn+gs+gw+ge) / sqrt(eps + gn^2+gs^2+gw^2+ge^2)  with
     g* the one-sided differences — an anisotropic-diffusion style update.
     """
-    c = x[..., 1:-1, 1:-1]
-    gn = x[..., :-2, 1:-1] - c
-    gs = x[..., 2:, 1:-1] - c
-    gw = x[..., 1:-1, :-2] - c
-    ge = x[..., 1:-1, 2:] - c
+    c = at((0, 0))
+    gn = at((-1, 0)) - c
+    gs = at((1, 0)) - c
+    gw = at((0, -1)) - c
+    ge = at((0, 1)) - c
     num = gn + gs + gw + ge
     den = gn * gn + gs * gs + gw * gw + ge * ge
-    eps = jnp.asarray(1e-3, x.dtype)
-    dt = jnp.asarray(0.1, x.dtype)
+    eps = jnp.asarray(1e-3, c.dtype)
+    dt = jnp.asarray(0.1, c.dtype)
     return c + dt * num * jax_rsqrt(den + eps)
 
 
@@ -130,7 +148,7 @@ def _make_box(radius: int) -> Stencil:
         kind="box",
         flops_per_elem=2 * pts - 1,
         points=pts,
-        _step_valid=_linear_step(c),
+        _update=_linear_update(c),
         coeffs=c,
     )
 
@@ -144,25 +162,25 @@ def _make_star(radius: int) -> Stencil:
         kind="star",
         flops_per_elem=2 * pts - 1,
         points=pts,
-        _step_valid=_linear_step(c),
+        _update=_linear_update(c),
         coeffs=c,
     )
 
 
-def _heat3d_step(x: jnp.ndarray) -> jnp.ndarray:
+def _heat3d_update(at: Neighbours) -> jnp.ndarray:
     """3-D 7-point heat (star) stencil: explicit Euler Laplacian update.
 
     ``c + dt * (sum of 6 face neighbours - 6c)`` with ``dt = 0.1`` —
     weights sum to 1 and stay non-negative, so iterates remain bounded.
     """
-    c = x[..., 1:-1, 1:-1, 1:-1]
+    c = at((0, 0, 0))
     lap = (
-        x[..., :-2, 1:-1, 1:-1] + x[..., 2:, 1:-1, 1:-1]
-        + x[..., 1:-1, :-2, 1:-1] + x[..., 1:-1, 2:, 1:-1]
-        + x[..., 1:-1, 1:-1, :-2] + x[..., 1:-1, 1:-1, 2:]
+        at((-1, 0, 0)) + at((1, 0, 0))
+        + at((0, -1, 0)) + at((0, 1, 0))
+        + at((0, 0, -1)) + at((0, 0, 1))
     )
-    dt = jnp.asarray(0.1, x.dtype)
-    six = jnp.asarray(6.0, x.dtype)
+    dt = jnp.asarray(0.1, c.dtype)
+    six = jnp.asarray(6.0, c.dtype)
     return c + dt * (lap - six * c)
 
 
@@ -176,7 +194,7 @@ REGISTRY["heat3d1r"] = Stencil(
     kind="heat",
     flops_per_elem=13,
     points=7,
-    _step_valid=_heat3d_step,
+    _update=_heat3d_update,
     coeffs=None,
     ndim=3,
 )
@@ -186,7 +204,7 @@ REGISTRY["gradient2d"] = Stencil(
     kind="gradient",
     flops_per_elem=19,
     points=5,
-    _step_valid=_gradient_step,
+    _update=_gradient_update,
     coeffs=None,
 )
 

@@ -41,12 +41,14 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 
 from repro.core.stencil import Stencil, get_stencil
-from repro.kernels import DEFAULT_TILE, MXU_TILE, ceil_div
+from repro.kernels import (
+    DEFAULT_TILE, MXU_TILE, VMEM_LIMIT_BYTES, band_tiling,
+)
 
 __all__ = [
     "DispatchPolicy", "KernelImpl", "KERNEL_IMPLS",
     "register_kernel_impl", "select_kernel", "modeled_kernel_time",
-    "kernel_op_features",
+    "kernel_op_features", "interpret_mode",
 ]
 
 # engine-facing fused-step signature:
@@ -89,7 +91,9 @@ class KernelImpl:
     vmem_slots: int = 1      # apron'd tiles resident at once (db = 2)
 
 
-def _interpret(policy: DispatchPolicy) -> bool:
+def interpret_mode(policy: DispatchPolicy) -> bool:
+    """Whether the Pallas impls run in interpret mode under ``policy``:
+    the explicit setting, else interpret off-TPU and compiled on TPU."""
     if policy.interpret is not None:
         return policy.interpret
     return (policy.backend or jax.default_backend()) != "tpu"
@@ -105,7 +109,7 @@ def _make_pallas(policy: DispatchPolicy) -> FusedStep:
     from repro.kernels.stencil_multistep import fused_stencil_band
 
     tile = policy.tile or DEFAULT_TILE
-    interpret = _interpret(policy)
+    interpret = interpret_mode(policy)
 
     def step(band, name, steps, keep_top=False, keep_bottom=False):
         return fused_stencil_band(band, name, steps, keep_top=keep_top,
@@ -119,7 +123,7 @@ def _make_pallas_db(policy: DispatchPolicy) -> FusedStep:
     from repro.kernels.stencil_multistep_db import fused_stencil_band_db
 
     tile = policy.tile or DEFAULT_TILE
-    interpret = _interpret(policy)
+    interpret = interpret_mode(policy)
 
     def step(band, name, steps, keep_top=False, keep_bottom=False):
         return fused_stencil_band_db(band, name, steps, keep_top=keep_top,
@@ -133,7 +137,7 @@ def _make_mxu(policy: DispatchPolicy) -> FusedStep:
     from repro.kernels.stencil_banded_mxu import banded_fused_stencil
 
     tile = policy.tile or MXU_TILE
-    interpret = _interpret(policy)
+    interpret = interpret_mode(policy)
 
     def step(band, name, steps, keep_top=False, keep_bottom=False):
         return banded_fused_stencil(band, name, steps, keep_top=keep_top,
@@ -181,11 +185,11 @@ register_kernel_impl(KernelImpl(
 ))
 
 
-def _auto_impl(st: Stencil, backend: str) -> str:
+def _auto_impl(st: Stencil, steps: int, backend: str) -> str:
     if backend == "tpu":
         from repro.kernels.stencil_banded_mxu import mxu_wins
 
-        return "mxu" if (st.is_linear and mxu_wins(st)) else "pallas_db"
+        return "mxu" if mxu_wins(st, steps) else "pallas_db"
     # off-TPU (this container, CI) the XLA-fused jnp path beats
     # interpret-mode Pallas by orders of magnitude
     return "reference"
@@ -215,7 +219,7 @@ def select_kernel(
     backend = policy.backend or jax.default_backend()
     name = policy.impl
     if name == "auto":
-        name = _auto_impl(st, backend)
+        name = _auto_impl(st, steps, backend)
     try:
         impl = KERNEL_IMPLS[name]
     except KeyError:
@@ -231,11 +235,6 @@ def select_kernel(
 # --------------------------------------------------------------- modeling
 
 
-def _clamped_tile(impl: KernelImpl, tile, h_out: int, X: int) -> Tuple[int, int]:
-    ty, tx = tile or impl.default_tile
-    return min(ty, h_out), min(tx, X)
-
-
 def kernel_op_features(impl_name: str, st, shape_in, steps: int,
                        keep_lo, keep_hi, itemsize: int,
                        hw=None, tile: Optional[Tuple[int, int]] = None):
@@ -244,8 +243,10 @@ def kernel_op_features(impl_name: str, st, shape_in, steps: int,
     Returns ``(mem_bytes, vpu_flops, mxu_flops)`` — the raw quantities
     the Sec. III kernel term divides by hardware rates — or ``None``
     when the implementation is infeasible for this geometry
-    (unsupported stencil, non-banded op on a tiled 2-D kernel, or an
-    apron'd tile set exceeding a modeled VMEM when ``hw`` is given).
+    (unsupported stencil, non-banded op on a tiled 2-D kernel, or a
+    kernel whose modelled VMEM — :meth:`repro.kernels.BandTiling.vmem_bytes`
+    — exceeds the scoped limit the kernels request,
+    ``min(hw.c_vmem, VMEM_LIMIT_BYTES)``, when ``hw`` models a VMEM).
     :func:`modeled_kernel_time` sums these over a plan; the calibration
     harness (:mod:`repro.core.calibrate`) fits measured wall clock
     against the same features, so fitted rates mean exactly what the
@@ -255,8 +256,10 @@ def kernel_op_features(impl_name: str, st, shape_in, steps: int,
 
     * ``reference`` — no on-chip reuse across fused steps: every step
       streams the band through HBM once (read + write);
-    * ``pallas`` / ``pallas_db`` / ``mxu`` — one apron'd tile read per
-      output tile plus one exact band write per fused call.
+    * ``pallas`` / ``pallas_db`` / ``mxu`` — one apron'd tile DMA per
+      output tile (the aligned :class:`repro.kernels.BandTiling` window)
+      plus one exact band write per fused call; ``mxu`` also counts the
+      whole-tile banded matmuls it runs.
     """
     impl = KERNEL_IMPLS[impl_name]
     if not impl.supports(st, steps):
@@ -264,7 +267,7 @@ def kernel_op_features(impl_name: str, st, shape_in, steps: int,
     r, m = st.radius, steps
     from repro.core.plan import fused_box_geometry
 
-    shape_out, _, flops, elements = fused_box_geometry(
+    shape_out, _, flops, _ = fused_box_geometry(
         r, st.flops_per_elem, shape_in, m, keep_lo, keep_hi, itemsize)
     mem_bytes = 0.0
     mxu_flops = 0.0
@@ -283,20 +286,22 @@ def kernel_op_features(impl_name: str, st, shape_in, steps: int,
         # plans are reference-only for now
         return None
     else:
-        h_out, width = shape_out[0], shape_in[1]
-        ty, tx = _clamped_tile(impl, tile, h_out, width)
-        if ty <= 0 or tx <= 0:
+        try:
+            g = band_tiling(shape_in, r, m, keep_lo[0], keep_hi[0],
+                            tile or impl.default_tile, itemsize)
+        except ValueError:      # no output row left after m steps
             return None
-        apron_bytes = (ty + 2 * m * r) * (tx + 2 * m * r) * itemsize
+        n_taps = 2 * r + 1 if impl_name == "mxu" else 0
         c_vmem = getattr(hw, "c_vmem", 0) if hw is not None else 0
-        if c_vmem and apron_bytes * impl.vmem_slots > c_vmem:
+        if c_vmem and g.vmem_bytes(itemsize, impl.vmem_slots, n_taps) > \
+                min(c_vmem, VMEM_LIMIT_BYTES):
             return None
-        n_tiles = ceil_div(h_out, ty) * ceil_div(width, tx)
-        # reads: one apron'd tile per output tile; writes: exact band
-        mem_bytes += n_tiles * apron_bytes + h_out * width * itemsize
+        # reads: one apron'd tile DMA per output tile; writes: exact band
+        mem_bytes += g.n_tiles * g.th * g.tw * itemsize \
+            + shape_out[0] * shape_in[1] * itemsize
         if impl_name == "mxu":
-            n = 2 * r + 1
-            mxu_flops += elements * n * 2 * (tx + 2 * r)
+            # (2r+1) (th x tw) @ (tw x tw) matmuls per tile and step
+            mxu_flops += m * g.n_tiles * n_taps * 2 * g.th * g.tw * g.tw
     return mem_bytes, float(flops), mxu_flops
 
 

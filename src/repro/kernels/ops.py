@@ -5,19 +5,12 @@ works on this CPU container (validation) and on a real TPU (deployment).
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
+from .dispatch import DispatchPolicy, interpret_mode
 from .stencil_multistep import DEFAULT_TILE, fused_stencil_band
 
 __all__ = ["fused_stencil", "kernel_fused_step"]
-
-
-@functools.lru_cache(maxsize=1)
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def fused_stencil(
@@ -30,7 +23,7 @@ def fused_stencil(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = interpret_mode(DispatchPolicy())
     return fused_stencil_band(
         band, name, steps, keep_top=keep_top, keep_bottom=keep_bottom,
         tile=tile, interpret=interpret,

@@ -7,14 +7,23 @@ stencil as ``(2r+1)`` banded matmuls that run on the 197 TFLOP/s MXU:
 
     out = sum_dy  shift_dy(tile) @ B_dy,     B_dy[x+dx, x] = c[dy, dx]
 
-Efficiency per output element = (2r+1) · 2 · (TX + 2r) MXU-flops vs
-``2(2r+1)^2`` VPU-flops.  With TX = 128 (MXU-native) the MXU path wins
-when  (2r+1)·2·(TX+2r)/197e12  <  2(2r+1)^2/3.9e12, i.e. radius >= 3:
-box2d4r 2448/197T = 12.4 ps vs 161/3.9T = 41 ps  (~3.3x).
+Both this kernel and the VPU kernels compute whole apron'd tiles, so
+:func:`mxu_wins` charges each its own tile's work per output cell:
+``(2r+1)`` ``(th, tw) @ (tw, tw)`` matmuls per step here, against
+``flops_per_elem`` per tile cell on the VPU.  At the napkin rates the
+recast wins at radius 4 (box2d4r, k_on = 4: ~53 ps vs ~58 ps per output
+cell per step) and loses below.  The napkin charges the MXU its bf16
+peak; f32 at ``Precision.HIGHEST`` takes several passes, so a measured
+profile may move the threshold.
 
-Same masked in-place centre-update validity scheme as
-``stencil_multistep.py``; identical band semantics; oracle-validated in
-interpret mode (`tests/test_kernels.py::test_banded_mxu_kernel`).
+Same masked whole-tile update and padded geometry as
+``stencil_multistep.py`` (:mod:`repro.kernels.band`): each step's row
+shifts are whole-tile rolls, and the banded matrices map the full tile
+width onto itself (taps that would leave the tile are dropped; those
+cells are outside the valid region anyway).  The tile multiplies in f32
+at ``Precision.HIGHEST``, so the recast keeps f32 accuracy; identical band
+semantics; oracle-validated in interpret mode
+(`tests/test_kernels.py::test_banded_mxu_kernel`).
 """
 from __future__ import annotations
 
@@ -27,80 +36,79 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.reference import multi_step_band
 from repro.core.stencil import Stencil, get_stencil
-from repro.kernels import MXU_TILE, ceil_div
+from repro.kernels import DEFAULT_TILE as VPU_TILE
+from repro.kernels import MXU_TILE, BandTiling, band_tiling
+from repro.kernels.band import (
+    compiler_params, frame_mask, output_block, pad_band, shifted,
+    tile_origin,
+)
 
 __all__ = ["banded_fused_stencil", "mxu_wins"]
 
 DEFAULT_TILE = MXU_TILE  # lane dim 128 = MXU-native
 
 
-def mxu_wins(st: Stencil, tx: int = 128,
+def mxu_wins(st: Stencil, steps: int = 1, tx: int = MXU_TILE[1],
              vpu: float = 3.9e12, mxu: float = 197e12) -> bool:
-    """Napkin check: does the banded-MXU recast beat the VPU path?"""
+    """Napkin check: does the banded-MXU recast beat the VPU path?
+
+    Both kernels compute whole apron'd tiles (:func:`band_tiling`), so
+    each side is charged its tile's work per output cell: the MXU runs
+    ``2r+1`` ``(th, tw) @ (tw, tw)`` matmuls per step, the VPU
+    ``flops_per_elem`` per tile cell (its default tile)."""
     if not st.is_linear:
         return False
-    n = 2 * st.radius + 1
-    t_mxu = n * 2 * (tx + 2 * st.radius) / mxu
-    t_vpu = st.flops_per_elem / vpu
+
+    def tile(rows, lanes):
+        g = band_tiling((rows + 2 * steps * st.radius, lanes), st.radius,
+                        steps, False, False, (rows, lanes), 4)
+        return g.tw, g.th * g.tw / (g.ty * g.tx)   # cells per output cell
+
+    tw, mxu_cells = tile(MXU_TILE[0], tx)
+    _, vpu_cells = tile(*VPU_TILE)
+    t_mxu = mxu_cells * (2 * st.radius + 1) * 2 * tw / mxu
+    t_vpu = vpu_cells * st.flops_per_elem / vpu
     return t_mxu < t_vpu
 
 
-def _band_matrices(st: Stencil, tx: int) -> np.ndarray:
-    """(2r+1, TX+2r, TX) banded matrices, one per row offset dy."""
+def _band_matrices(st: Stencil, tw: int) -> np.ndarray:
+    """(2r+1, TW, TW) banded matrices, one per row offset dy:
+    ``(rows @ B[dy])[:, x] = sum_dx c[dy, dx] * rows[:, x + dx - r]``."""
     r = st.radius
     n = 2 * r + 1
-    out = np.zeros((n, tx + 2 * r, tx), np.float32)
+    out = np.zeros((n, tw, tw), np.float32)
     for dy in range(n):
         for dx in range(n):
             c = float(st.coeffs[dy, dx])
-            for x in range(tx):
-                out[dy, x + dx, x] = c
+            for x in range(max(r - dx, 0), min(tw, tw + r - dx)):
+                out[dy, x + dx - r, x] = c
     return out
 
 
-def _kernel(x_hbm, bands_ref, o_ref, tile, sem, *, st, steps, keep_top,
-            keep_bottom, H, X, Hp, Xp, TY, TX):
+def _kernel(x_hbm, bands_ref, o_ref, tile, sem, *, st: Stencil, steps: int,
+            keep_top: bool, keep_bottom: bool, H: int, X: int,
+            g: BandTiling):
     r = st.radius
-    m = steps
-    n = 2 * r + 1
-    TH, TW = TY + 2 * m * r, TX + 2 * m * r
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    oy = i * TY + (0 if keep_top else m * r)
-    ox = j * TX
-    sy = jnp.clip(oy - m * r, 0, Hp - TH)
-    sx = jnp.clip(ox - m * r, 0, Xp - TW)
+    y0, x0 = tile_origin(pl.program_id(0), pl.program_id(1), g,
+                         x_hbm.dtype.itemsize)
     copy = pltpu.make_async_copy(
-        x_hbm.at[pl.ds(sy, TH), pl.ds(sx, TW)], tile, sem
-    )
+        x_hbm.at[pl.ds(y0, g.th), pl.ds(x0, g.tw)], tile, sem)
     copy.start()
     copy.wait()
-    t = tile[...]
-
-    grow = sy + jax.lax.broadcasted_iota(jnp.int32, (TH, TW), 0)
-    gcol = sx + jax.lax.broadcasted_iota(jnp.int32, (TH, TW), 1)
-    updatable = (gcol >= r) & (gcol < X - r)
-    if keep_top:
-        updatable &= grow >= r
-    if keep_bottom:
-        updatable &= grow < H - r
-
-    bands = bands_ref[...]
-    for s in range(m):
-        # centre via (2r+1) banded matmuls on the MXU; band matrices map
-        # the full tile width TW onto the centre TW - 2r
+    updatable = frame_mask(y0, x0, g, r, H, X, keep_top, keep_bottom)
+    t = tile[...].astype(jnp.float32)
+    for _ in range(steps):
+        # (2r+1) banded matmuls on the MXU, one per row offset
+        at = shifted(t)
         acc = None
-        for dy in range(n):
-            rows = t[dy : TH - (n - 1) + dy, :]          # (TH-2r, TW)
-            term = jnp.dot(rows, bands[dy].astype(t.dtype),
+        for dy in range(2 * r + 1):
+            term = jnp.dot(at((dy - r, 0)), bands_ref[dy],
+                           precision=jax.lax.Precision.HIGHEST,
                            preferred_element_type=jnp.float32)
             acc = term if acc is None else acc + term
-        upd = t.at[r:-r, r:-r].set(acc.astype(t.dtype))
-        t = jnp.where(updatable, upd, t)
-    out = jax.lax.dynamic_slice(t, (oy - sy, ox - sx), (TY, TX))
-    o_ref[...] = out
+        t = jnp.where(updatable, acc, t)
+    o_ref[...] = output_block(t, g).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -120,47 +128,29 @@ def banded_fused_stencil(
     st = get_stencil(name)
     if not st.is_linear:
         raise ValueError(f"{name} is nonlinear; banded-MXU path needs coeffs")
-    r, m = st.radius, steps
     H, X = band.shape
-    h_out = H - 2 * m * r + (int(keep_top) + int(keep_bottom)) * m * r
-    if h_out <= 0:
-        raise ValueError(f"band of {H} rows too small for {m} fused steps")
-
-    ty = min(tile[0], h_out)
-    tx = min(tile[1], X)
-    if H < ty + 2 * m * r or X < tx + 2 * m * r:
-        return multi_step_band(band, name, steps, keep_top, keep_bottom)
-
-    grid = (ceil_div(h_out, ty), ceil_div(X, tx))
-    hp_out, xp_out = grid[0] * ty, grid[1] * tx
-    pad_y, pad_x = hp_out - h_out, xp_out - X
-    Hp, Xp = H + pad_y, X + pad_x
-    if pad_y or pad_x:
-        band = jnp.pad(band, ((0, pad_y), (0, pad_x)))
-
-    # band matrices: (n, TW, TW-2r) — full tile width in, centre width out,
-    # passed as a (small) VMEM-resident input replicated to every tile
-    tw = tx + 2 * m * r
-    bands = jnp.asarray(_band_matrices(st, tw - 2 * r))
-
-    kern = functools.partial(
-        _kernel, st=st, steps=m, keep_top=keep_top,
-        keep_bottom=keep_bottom, H=H, X=X, Hp=Hp, Xp=Xp, TY=ty, TX=tx,
-    )
-    n = 2 * r + 1
+    g = band_tiling((H, X), st.radius, steps, keep_top, keep_bottom, tile,
+                    band.dtype.itemsize)
+    # (n, TW, TW) band matrices: a small VMEM-resident input, the same
+    # block for every tile
+    bands = jnp.asarray(_band_matrices(st, g.tw))
+    n = bands.shape[0]
+    kern = functools.partial(_kernel, st=st, steps=steps, keep_top=keep_top,
+                             keep_bottom=keep_bottom, H=H, X=X, g=g)
     out = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(g.ny, g.nx),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((n, tw, tw - 2 * r), lambda i, j: (0, 0, 0)),
+            pl.BlockSpec((n, g.tw, g.tw), lambda i, j: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((ty, tx), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((hp_out, xp_out), band.dtype),
+        out_specs=pl.BlockSpec((g.ty, g.tx), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((g.ny * g.ty, g.nx * g.tx), band.dtype),
         scratch_shapes=[
-            pltpu.VMEM((ty + 2 * m * r, tx + 2 * m * r), band.dtype),
+            pltpu.VMEM((g.th, g.tw), band.dtype),
             pltpu.SemaphoreType.DMA,
         ],
+        compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
-    )(band, bands)
-    return out[:h_out, :X]
+    )(pad_band(band, g), bands)
+    return out[:g.h_out, :X]

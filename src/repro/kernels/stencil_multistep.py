@@ -7,18 +7,13 @@ analogue of the paper's register/shared-memory reuse), and writes the tile
 back.  The tile aprons are recomputed by neighbouring tiles — the on-chip
 incarnation of SO2DR's deliberate redundant computation.
 
-Correctness scheme — *masked in-place centre update*: the VMEM tile keeps
-its full shape across steps; each step overwrites the tile centre
-``t[r:-r, r:-r]`` with the stencil update, then a global-index mask
-re-protects Dirichlet frame cells (row frames if ``keep_top``/
-``keep_bottom``; column frames always).  After ``s`` steps a tile cell is
-valid iff it is ``>= s*r`` from every tile edge *or* backed by frame, so
-tiles are positioned (with clamped DMA starts at band edges) such that the
-final output slice is always valid.  The wrapper pads the band to
-tile-divisible sizes; pad cells are never read by valid cells.
-
-Semantics match :func:`repro.core.reference.multi_step_band` exactly
-(column frames always preserved; ``keep_top``/``keep_bottom`` row frames).
+Correctness scheme — the masked whole-tile update of
+:mod:`repro.kernels.band`: the wrapper pads the band so every tile's DMA
+window starts at an aligned offset and its output block sits at the
+static in-tile offset ``(m*r, m*r)``.  Semantics match
+:func:`repro.core.reference.multi_step_band` exactly (column frames
+always preserved; ``keep_top``/``keep_bottom`` row frames), for any band
+that yields at least one output row.
 """
 from __future__ import annotations
 
@@ -30,63 +25,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.reference import multi_step_band
 from repro.core.stencil import Stencil, get_stencil
-from repro.kernels import DEFAULT_TILE, ceil_div
+from repro.kernels import DEFAULT_TILE, BandTiling, band_tiling
+from repro.kernels.band import (
+    compiler_params, frame_mask, fused_steps, output_block, pad_band,
+    tile_origin,
+)
 
 __all__ = ["fused_stencil_band", "DEFAULT_TILE"]
 
 
-def _kernel(
-    x_hbm,
-    o_ref,
-    tile,
-    sem,
-    *,
-    st: Stencil,
-    steps: int,
-    keep_top: bool,
-    keep_bottom: bool,
-    H: int,          # true (unpadded) band height
-    X: int,          # true (unpadded) band width
-    Hp: int,         # padded band height
-    Xp: int,         # padded band width
-    TY: int,
-    TX: int,
-):
-    r = st.radius
-    m = steps
-    TH, TW = TY + 2 * m * r, TX + 2 * m * r
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    # output-tile origin in input coordinates
-    oy = i * TY + (0 if keep_top else m * r)
-    ox = j * TX
-    # clamped DMA start (tiles at band edges align with the frame)
-    sy = jnp.clip(oy - m * r, 0, Hp - TH)
-    sx = jnp.clip(ox - m * r, 0, Xp - TW)
+def _kernel(x_hbm, o_ref, tile, sem, *, st: Stencil, steps: int,
+            keep_top: bool, keep_bottom: bool, H: int, X: int,
+            g: BandTiling):
+    y0, x0 = tile_origin(pl.program_id(0), pl.program_id(1), g,
+                         x_hbm.dtype.itemsize)
     copy = pltpu.make_async_copy(
-        x_hbm.at[pl.ds(sy, TH), pl.ds(sx, TW)], tile, sem
-    )
+        x_hbm.at[pl.ds(y0, g.th), pl.ds(x0, g.tw)], tile, sem)
     copy.start()
     copy.wait()
-    t = tile[...]
-
-    # global-index frame mask: cells that must never update
-    grow = sy + jax.lax.broadcasted_iota(jnp.int32, (TH, TW), 0)
-    gcol = sx + jax.lax.broadcasted_iota(jnp.int32, (TH, TW), 1)
-    updatable = (gcol >= r) & (gcol < X - r)  # column frames always constant
-    if keep_top:
-        updatable &= grow >= r
-    if keep_bottom:
-        updatable &= grow < H - r
-
-    # k_on fused steps, entirely in VMEM (on-chip data reuse)
-    for _ in range(m):
-        upd = t.at[r:-r, r:-r].set(st.step_valid(t))
-        t = jnp.where(updatable, upd, t)
-    out = jax.lax.dynamic_slice(t, (oy - sy, ox - sx), (TY, TX))
-    o_ref[...] = out
+    updatable = frame_mask(y0, x0, g, st.radius, H, X, keep_top, keep_bottom)
+    t = fused_steps(tile[...].astype(jnp.float32), st, steps, updatable)
+    o_ref[...] = output_block(t, g).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -108,52 +68,22 @@ def fused_stencil_band(
     :func:`repro.core.reference.multi_step_band`.
     """
     st = get_stencil(name)
-    r, m = st.radius, steps
     H, X = band.shape
-    h_out = H - 2 * m * r + (int(keep_top) + int(keep_bottom)) * m * r
-    if h_out <= 0:
-        raise ValueError(f"band of {H} rows too small for {m} fused steps")
-
-    # effective tile sizes: the DMA region (tile + 2mr apron) must fit
-    ty = min(tile[0], h_out)
-    tx = min(tile[1], X)
-    if H < ty + 2 * m * r or X < tx + 2 * m * r:
-        # band smaller than one apron'd tile — tiny-shape fallback
-        return multi_step_band(band, name, steps, keep_top, keep_bottom)
-
-    # pad band so every output tile lies fully inside the padded band
-    grid = (ceil_div(h_out, ty), ceil_div(X, tx))
-    hp_out = grid[0] * ty
-    xp_out = grid[1] * tx
-    pad_y = hp_out - h_out
-    pad_x = xp_out - X
-    Hp, Xp = H + pad_y, X + pad_x
-    if pad_y or pad_x:
-        band = jnp.pad(band, ((0, pad_y), (0, pad_x)))
-
-    kern = functools.partial(
-        _kernel,
-        st=st,
-        steps=m,
-        keep_top=keep_top,
-        keep_bottom=keep_bottom,
-        H=H,
-        X=X,
-        Hp=Hp,
-        Xp=Xp,
-        TY=ty,
-        TX=tx,
-    )
+    g = band_tiling((H, X), st.radius, steps, keep_top, keep_bottom, tile,
+                    band.dtype.itemsize)
+    kern = functools.partial(_kernel, st=st, steps=steps, keep_top=keep_top,
+                             keep_bottom=keep_bottom, H=H, X=X, g=g)
     out = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(g.ny, g.nx),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((ty, tx), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((hp_out, xp_out), band.dtype),
+        out_specs=pl.BlockSpec((g.ty, g.tx), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((g.ny * g.ty, g.nx * g.tx), band.dtype),
         scratch_shapes=[
-            pltpu.VMEM((ty + 2 * m * r, tx + 2 * m * r), band.dtype),
+            pltpu.VMEM((g.th, g.tw), band.dtype),
             pltpu.SemaphoreType.DMA,
         ],
+        compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
-    )(band)
-    return out[:h_out, :X]
+    )(pad_band(band, g))
+    return out[:g.h_out, :X]

@@ -6,9 +6,10 @@ inside the kernel: two VMEM slots + two DMA semaphores, tile ``g+1``'s
 HBM→VMEM copy issued before tile ``g``'s compute so the systolic/vector
 units never wait on HBM in steady state.
 
-Grid is 1-D over tiles (row-major) so the pipeline is explicit.  Same
-masked in-place centre-update semantics as ``stencil_multistep.py``;
-oracle-validated in interpret mode.
+Grid is 1-D over tiles (row-major) so the pipeline is explicit, and runs
+in order (``arbitrary``): each step waits on the copy its predecessor
+started.  Same masked whole-tile update and padded geometry as
+``stencil_multistep.py`` (:mod:`repro.kernels.band`).
 """
 from __future__ import annotations
 
@@ -20,67 +21,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.reference import multi_step_band
 from repro.core.stencil import Stencil, get_stencil
-from repro.kernels import DEFAULT_TILE, ceil_div
+from repro.kernels import DEFAULT_TILE, BandTiling, band_tiling
+from repro.kernels.band import (
+    compiler_params, frame_mask, fused_steps, output_block, pad_band,
+    tile_origin,
+)
 
 __all__ = ["fused_stencil_band_db"]
 
 
 def _kernel(x_hbm, o_ref, tiles, sems, *, st: Stencil, steps: int,
-            keep_top: bool, keep_bottom: bool, H, X, Hp, Xp, TY, TX, NX, NT):
-    r = st.radius
-    m = steps
-    TH, TW = TY + 2 * m * r, TX + 2 * m * r
-    g = pl.program_id(0)
+            keep_top: bool, keep_bottom: bool, H: int, X: int,
+            g: BandTiling):
+    k = pl.program_id(0)
+    itemsize = x_hbm.dtype.itemsize
 
-    def start(gi, slot):
-        i = gi // NX
-        j = gi % NX
-        oy = i * TY + (0 if keep_top else m * r)
-        ox = j * TX
-        sy = jnp.clip(oy - m * r, 0, Hp - TH)
-        sx = jnp.clip(ox - m * r, 0, Xp - TW)
-        pltpu.make_async_copy(
-            x_hbm.at[pl.ds(sy, TH), pl.ds(sx, TW)],
-            tiles.at[slot], sems.at[slot],
-        ).start()
-        return sy, sx
+    def copy(gi, slot):
+        y0, x0 = tile_origin(gi // g.nx, gi % g.nx, g, itemsize)
+        return pltpu.make_async_copy(
+            x_hbm.at[pl.ds(y0, g.th), pl.ds(x0, g.tw)],
+            tiles.at[slot], sems.at[slot])
 
-    # prologue: first tile fetches itself
-    @pl.when(g == 0)
+    # prologue: the first tile fetches itself
+    @pl.when(k == 0)
     def _():
-        start(g, g % 2)
+        copy(k, 0).start()
 
     # steady state: prefetch the NEXT tile into the other slot
-    @pl.when(g + 1 < NT)
+    @pl.when(k + 1 < g.n_tiles)
     def _():
-        start(g + 1, (g + 1) % 2)
+        copy(k + 1, (k + 1) % 2).start()
 
-    # wait for this tile's DMA (recompute its descriptor for the wait)
-    i = g // NX
-    j = g % NX
-    oy = i * TY + (0 if keep_top else m * r)
-    ox = j * TX
-    sy = jnp.clip(oy - m * r, 0, Hp - TH)
-    sx = jnp.clip(ox - m * r, 0, Xp - TW)
-    pltpu.make_async_copy(
-        x_hbm.at[pl.ds(sy, TH), pl.ds(sx, TW)],
-        tiles.at[g % 2], sems.at[g % 2],
-    ).wait()
-
-    t = tiles[g % 2]
-    grow = sy + jax.lax.broadcasted_iota(jnp.int32, (TH, TW), 0)
-    gcol = sx + jax.lax.broadcasted_iota(jnp.int32, (TH, TW), 1)
-    updatable = (gcol >= r) & (gcol < X - r)
-    if keep_top:
-        updatable &= grow >= r
-    if keep_bottom:
-        updatable &= grow < H - r
-    for _ in range(m):
-        upd = t.at[r:-r, r:-r].set(st.step_valid(t))
-        t = jnp.where(updatable, upd, t)
-    o_ref[...] = jax.lax.dynamic_slice(t, (oy - sy, ox - sx), (TY, TX))
+    slot = k % 2
+    copy(k, slot).wait()
+    y0, x0 = tile_origin(k // g.nx, k % g.nx, g, itemsize)
+    updatable = frame_mask(y0, x0, g, st.radius, H, X, keep_top, keep_bottom)
+    t = fused_steps(tiles[slot].astype(jnp.float32), st, steps,
+                    updatable)
+    o_ref[...] = output_block(t, g).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -97,37 +76,23 @@ def fused_stencil_band_db(
     interpret: bool = True,
 ) -> jnp.ndarray:
     st = get_stencil(name)
-    r, m = st.radius, steps
     H, X = band.shape
-    h_out = H - 2 * m * r + (int(keep_top) + int(keep_bottom)) * m * r
-    if h_out <= 0:
-        raise ValueError(f"band of {H} rows too small for {m} fused steps")
-    ty = min(tile[0], h_out)
-    tx = min(tile[1], X)
-    if H < ty + 2 * m * r or X < tx + 2 * m * r:
-        return multi_step_band(band, name, steps, keep_top, keep_bottom)
-
-    ny, nx = ceil_div(h_out, ty), ceil_div(X, tx)
-    hp_out, xp_out = ny * ty, nx * tx
-    pad_y, pad_x = hp_out - h_out, xp_out - X
-    Hp, Xp = H + pad_y, X + pad_x
-    if pad_y or pad_x:
-        band = jnp.pad(band, ((0, pad_y), (0, pad_x)))
-
-    kern = functools.partial(
-        _kernel, st=st, steps=m, keep_top=keep_top, keep_bottom=keep_bottom,
-        H=H, X=X, Hp=Hp, Xp=Xp, TY=ty, TX=tx, NX=nx, NT=ny * nx,
-    )
+    g = band_tiling((H, X), st.radius, steps, keep_top, keep_bottom, tile,
+                    band.dtype.itemsize)
+    kern = functools.partial(_kernel, st=st, steps=steps, keep_top=keep_top,
+                             keep_bottom=keep_bottom, H=H, X=X, g=g)
+    nx = g.nx
     out = pl.pallas_call(
         kern,
-        grid=(ny * nx,),
+        grid=(g.n_tiles,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((ty, tx), lambda g: (g // nx, g % nx)),
-        out_shape=jax.ShapeDtypeStruct((hp_out, xp_out), band.dtype),
+        out_specs=pl.BlockSpec((g.ty, g.tx), lambda k: (k // nx, k % nx)),
+        out_shape=jax.ShapeDtypeStruct((g.ny * g.ty, g.nx * g.tx), band.dtype),
         scratch_shapes=[
-            pltpu.VMEM((2, ty + 2 * m * r, tx + 2 * m * r), band.dtype),
+            pltpu.VMEM((2, g.th, g.tw), band.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
-    )(band)
-    return out[:h_out, :X]
+    )(pad_band(band, g))
+    return out[:g.h_out, :X]

@@ -5,7 +5,8 @@ touches jax device state.
 """
 from __future__ import annotations
 
-from ..compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "data_axes"]
 

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from ..compat import shard_map
+from jax import shard_map
 from .layers import dense_init
 
 __all__ = ["moe_init", "moe_apply", "set_moe_block_dispatch"]

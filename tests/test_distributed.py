@@ -9,7 +9,8 @@ import numpy as np
 import jax.numpy as jnp
 
 from _subproc import run_fake_device_subprocess
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core.distributed import (
     collective_bytes_per_round, run_distributed,
 )
@@ -18,7 +19,8 @@ from repro.core.stencil import get_stencil
 
 _SUBPROC = r"""
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core.distributed import run_distributed
 from repro.core.reference import run_reference
 from repro.core.stencil import get_stencil

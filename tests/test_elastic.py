@@ -8,7 +8,8 @@ from _subproc import run_fake_device_subprocess
 _SUBPROC = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_smoke_config
 from repro.models.api import build_model

@@ -94,7 +94,8 @@ def test_hier_lossy_codec_stays_within_its_error_bound():
 
 _SUBPROC = r"""
 import numpy as np, jax.numpy as jnp
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core.executor import ShardMapExecutor, ShardedSimExecutor
 from repro.core.hierarchy import compile_hierarchical
 from repro.core.reference import run_reference

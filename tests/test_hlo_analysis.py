@@ -2,7 +2,8 @@
 import jax
 import jax.numpy as jnp
 
-from repro.compat import AxisType, make_mesh, shard_map
+from jax import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.launch.hlo_analysis import analyze_hlo
 
 

@@ -4,6 +4,11 @@ import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st_h
 
+from repro.core.executor import EagerExecutor
+from repro.core.oocore import compile_plan
+from repro.core.reference import run_reference
+from repro.core.stencil import get_stencil
+from repro.kernels.dispatch import DispatchPolicy
 from repro.kernels.ops import fused_stencil
 from repro.kernels.ref import multi_step_band
 
@@ -44,8 +49,69 @@ def test_kernel_bf16():
 
 
 def test_kernel_tiny_band_fallback():
-    # band too small for one apron'd tile -> reference fallback path
+    """A band smaller than one apron'd tile has no reference fallback:
+    every kernel pads it into one tile, and the lowered executor names
+    the kernel that ran."""
     _check("box2d4r", 20, 40, 2, True, True, tile=(256, 512))
+    for impl, fn in IMPLS.items():
+        _check_impl(fn, "box2d4r", 20, 40, 2, True, True, tile=(256, 512))
+    st = get_stencil("box2d1r")
+    x = RNG.standard_normal((14, 14)).astype(np.float32)
+    plan = compile_plan("so2dr", st, 14, 14, 4, 2, 2, 2)
+    ex = EagerExecutor(policy=DispatchPolicy(impl="pallas_db"))
+    out, _ = ex.execute(plan, x)
+    assert ex.exec_stats.kernel_impl == "pallas_db"
+    ref = np.asarray(run_reference(jnp.asarray(x), st, 4))
+    assert np.abs(out - ref).max() < 1e-5
+
+
+def _impls():
+    from repro.kernels.stencil_banded_mxu import banded_fused_stencil
+    from repro.kernels.stencil_multistep import fused_stencil_band
+    from repro.kernels.stencil_multistep_db import fused_stencil_band_db
+
+    return {"pallas": fused_stencil_band, "pallas_db": fused_stencil_band_db,
+            "mxu": banded_fused_stencil}
+
+
+IMPLS = _impls()
+
+
+def _check_impl(fn, name, H, X, steps, kt, kb, tile=(16, 128),
+                dtype=jnp.float32, tol=1e-5):
+    x = RNG.standard_normal((H, X)).astype(np.float32)
+    xb = jnp.asarray(x, dtype)
+    # oracle in f32 on the same (possibly bf16-rounded) input: the tiles
+    # compute in f32, so only the final rounding to ``dtype`` differs
+    ref = np.asarray(multi_step_band(xb.astype(jnp.float32), name, steps,
+                                     kt, kb))
+    got = fn(xb, name, steps, kt, kb, tile=tile)
+    assert got.dtype == dtype and got.shape == ref.shape
+    err = np.abs(np.asarray(got, np.float32) - ref).max()
+    scale = np.abs(ref).max() + 1e-6
+    assert err / scale < tol, (name, H, X, steps, kt, kb, err)
+
+
+KEEPS = [(False, False), (True, False), (False, True), (True, True)]
+# (stencil, steps, H, X) on a (16, 128) tile: widths off the 128-lane
+# grid, and bands just above one apron'd tile (one extra output row or
+# column spills into a second tile)
+GEOMETRIES = [("box2d1r", 2, 21, 129), ("box2d2r", 2, 37, 200),
+              ("gradient2d", 3, 40, 131), ("box2d4r", 1, 27, 300)]
+
+
+@pytest.mark.parametrize("kt,kb", KEEPS)
+@pytest.mark.parametrize("impl,name,steps,H,X", [
+    (impl, *geo) for impl in IMPLS for geo in GEOMETRIES
+    if impl != "mxu" or geo[0] != "gradient2d"])
+def test_padded_geometry_matches_oracle(impl, name, steps, H, X, kt, kb):
+    _check_impl(IMPLS[impl], name, H, X, steps, kt, kb)
+
+
+@pytest.mark.parametrize("impl", sorted(IMPLS))
+def test_bf16_band_per_impl(impl):
+    _check_impl(IMPLS[impl], "box2d1r", 37, 150, 3, True, False,
+                dtype=jnp.bfloat16, tol=1e-2)
 
 
 @settings(max_examples=15, deadline=None)

@@ -55,7 +55,8 @@ def _domain(Y=48, X=48, seed=None):
 
 _SUBPROC = r"""
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.core.distributed import run_distributed
 from repro.core.executor import ShardMapExecutor, ShardedSimExecutor
 from repro.core.reference import run_reference
