@@ -30,9 +30,10 @@ programs of pre-bound closures (no per-op ``isinstance`` dispatch),
 FusedKernel ops resolve through the kernel-dispatch registry
 (:mod:`repro.kernels.dispatch`), band heights are padded to per-plan
 shape buckets so chunks/rounds share one compiled kernel signature, and
-an :class:`~repro.core.lower.ExecStats` with per-op-class wall clock and
-compilation-cache counters lands on ``executor.exec_stats`` after every
-run.  ``lowered=False`` falls back to the original op-at-a-time
+an :class:`~repro.core.lower.ExecStats` with per-span wall clock (every
+op class and the phases of the fused step and the barrier, each also a
+profiler span) and compilation-cache counters lands on
+``executor.exec_stats`` after every run.  ``lowered=False`` falls back to the original op-at-a-time
 interpreter (:class:`_DeviceState`) — results are bitwise identical.
 
 All executors return ``(host_array | None, TransferStats)`` where the

@@ -12,8 +12,9 @@ compiles the plan once instead:
   chunk)`` stages of *pre-bound closures*: register/buffer names are
   resolved to integer slots, slice bounds and codec objects are baked
   into each closure, and per-op type dispatch disappears from the
-  execution loop (it runs ``for tag, fn, rnd, chunk in stage: fn(rt)``;
-  the trailing site pair addresses fault injection).
+  execution loop (:meth:`SpanRecorder.run` walks ``for tag, fn, rnd,
+  chunk in stage``; the trailing site pair addresses fault injection and
+  labels the op's profiler span).
 * **kernel dispatch** — FusedKernel ops are resolved through the
   registry in :mod:`repro.kernels.dispatch` (reference jnp, Pallas,
   DMA-overlapped Pallas, banded-MXU) exactly once at lowering time.
@@ -28,9 +29,13 @@ compiles the plan once instead:
 * **compilation cache** — a :class:`KernelCache` keyed by
   ``(impl, stencil, steps, keeps, bucket_height, width, itemsize)``
   counts distinct signatures; hits/misses surface in :class:`ExecStats`
-  alongside wall-clock per op class.  The d=8, 4-round SO2DR config
+  alongside wall-clock per span name.  The d=8, 4-round SO2DR config
   compiles at most one kernel per shape bucket instead of one per
   chunk x round.
+* **spans** — one :class:`SpanRecorder` per run times every bound op
+  (and every phase of the fused step and the barrier) into
+  :class:`ExecStats` and writes each as a flat profiler host event
+  carrying ``run``, ``round`` and ``chunk``, on the device trace's clock.
 
 Accounting is untouched: :meth:`CompiledPlan.execute` still returns the
 plan-derived :class:`~repro.core.plan.TransferStats`, so dry-run numbers,
@@ -41,8 +46,10 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import itertools
 import threading
 import time
+from collections import defaultdict
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -60,12 +67,12 @@ from .plan import (
 
 __all__ = [
     "ExecStats", "KernelCache", "BucketRegistry", "SlotPool",
-    "CompiledPlan", "LoweredStage", "lower",
+    "CompiledPlan", "LoweredStage", "SpanRecorder", "lower",
     "CompiledShardedPlan", "ShardStage", "lower_sharded",
     "check_domain", "validate_domain",
 ]
 
-# op-class tags (indices into the per-class wall-clock accumulators)
+# op-class tags: a bound op's first field; OP_TAGS[tag] names its span
 OP_TAGS = ("H2D", "D2H", "BufferWrite", "BufferRead", "FusedKernel",
            "HostCommit", "Compress", "Decompress",
            "ShardLoad", "ShardStore", "HaloSend", "HaloRecv", "ShardKernel",
@@ -77,14 +84,24 @@ _TAG = {name: i for i, name in enumerate(OP_TAGS)}
 # the closure runs, so an injected fault never leaves a half-executed op
 BoundOp = Tuple[int, Callable, int, int]
 
+# op classes whose closures open their own phase spans (pad/call/crop,
+# drain/pull/scatter): timed under the class name with no span of their own
+_PHASED = frozenset((_TAG["FusedKernel"], _TAG["HostCommit"]))
+# one per process, so no two runs in one trace share a ``run`` id
+_RUN_IDS = itertools.count(1)
+
 
 @dataclasses.dataclass
 class ExecStats:
     """Execution-side counters (wall clock + compilation cache), the
     companion of the plan-side :class:`~repro.core.plan.TransferStats`.
 
-    Wall-clock numbers are host-observed dispatch+compute time per op
-    class — meaningful for comparing executors/kernels on one machine,
+    ``op_counts``/``op_wall_s`` are keyed by span name
+    (:class:`SpanRecorder`): every op class, plus the phases
+    ``FusedKernel.{pad,call,crop}``, ``HostCommit.drain``,
+    ``D2H.{pull,decode,scatter}`` and ``Execute.validate`` (outside
+    ``wall_s``).  Wall-clock numbers are host-observed dispatch+compute
+    time — meaningful for comparing executors/kernels on one machine,
     never for gating CI (the cache/op counters are the deterministic
     part)."""
 
@@ -148,6 +165,88 @@ class ExecStats:
                 self.model_error = ((self.modeled_s - self.wall_s)
                                     / self.wall_s)
         return self
+
+
+class SpanRecorder:
+    """Host seconds and a count per span name for one run of a lowered
+    plan, each span also a profiler host event
+    (``jax.profiler.TraceAnnotation``: on the device trace's clock, and
+    almost free while no profiler runs).
+
+    Spans are leaves: each wraps one host action and none encloses
+    another, so a trace reader that names an idle stretch of the device
+    by the host event overlapping it most lands on the action, not on an
+    enclosing phase.  An op class in ``phased`` is timed and counted
+    under its class name with no event of its own; its closure opens
+    phase spans through :meth:`span` instead.  Every event carries
+    ``run`` (one id per recorder, i.e. per solve), ``round``, the op's
+    site under ``site`` (``chunk``, or ``rank`` on sharded plans) and
+    any fixed ``meta`` (``job`` in the interleaved scheduler)."""
+
+    __slots__ = ("site", "phased", "meta", "wall", "count")
+
+    def __init__(self, site: str = "chunk", phased: frozenset = _PHASED,
+                 **meta):
+        self.site = site
+        self.phased = phased
+        self.meta = {"run": next(_RUN_IDS), "round": -1, site: -1, **meta}
+        self.wall: Dict[str, float] = defaultdict(float)
+        self.count: Dict[str, int] = defaultdict(int)
+
+    def at(self, rnd: int, site: int) -> None:
+        self.meta["round"] = rnd
+        self.meta[self.site] = site
+
+    def span(self, name: str) -> "_Span":
+        """A context manager: one span named ``name`` around its body."""
+        return _Span(self, name)
+
+    def run(self, ops: Tuple[BoundOp, ...], rt, injector=None,
+            retry=None) -> None:
+        """Run bound ops against runtime ``rt``, each timed under its op
+        class, consulting ``injector`` first when one is given."""
+        perf = time.perf_counter
+        for tag, fn, rnd, site in ops:
+            name = OP_TAGS[tag]
+            if injector is not None:
+                consult(injector, retry, rnd, site, name)
+            self.at(rnd, site)
+            if tag in self.phased:
+                t0 = perf()
+                fn(rt)
+                self.wall[name] += perf() - t0
+                self.count[name] += 1
+            else:
+                with _Span(self, name):
+                    fn(rt)
+
+    def exec_stats(self, kernel_op: str, **fields) -> ExecStats:
+        """An :class:`ExecStats` of this run's spans; ``kernel_calls``
+        counts ``kernel_op``."""
+        return ExecStats(op_counts=dict(self.count),
+                         op_wall_s=dict(self.wall),
+                         kernel_calls=self.count.get(kernel_op, 0), **fields)
+
+
+class _Span:
+    """One leaf span of a :class:`SpanRecorder` (a class, not a
+    generator: about a microsecond cheaper per span)."""
+
+    __slots__ = ("rec", "name", "ann", "t0")
+
+    def __init__(self, rec: SpanRecorder, name: str):
+        self.rec, self.name = rec, name
+
+    def __enter__(self) -> None:
+        self.ann = jax.profiler.TraceAnnotation(self.name, **self.rec.meta)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
+        self.rec.wall[self.name] += dt
+        self.rec.count[self.name] += 1
 
 
 class KernelCache:
@@ -299,12 +398,14 @@ class _Runtime:
     against (the lowered counterpart of the executors' old name-keyed
     device state)."""
 
-    __slots__ = ("host", "regs", "bufs", "staged", "wire",
-                 "on_commit", "committed_round")
+    __slots__ = ("host", "regs", "bufs", "staged", "staged_chunks", "wire",
+                 "on_commit", "committed_round", "spans")
 
     def __init__(self, host: np.ndarray, n_regs: int, n_bufs: int,
-                 regs: Optional[List] = None, bufs: Optional[List] = None):
+                 regs: Optional[List] = None, bufs: Optional[List] = None,
+                 spans: Optional[SpanRecorder] = None):
         self.host = host
+        self.spans = spans if spans is not None else SpanRecorder()
         # recovery hooks: the newest round whose barrier fully drained
         # (-1 = none), and an optional per-round checkpoint callback
         self.on_commit: Optional[Callable[[int, np.ndarray], None]] = None
@@ -313,23 +414,38 @@ class _Runtime:
         # needed — closures only ever index their bound slots)
         self.regs: List = regs if regs is not None else [None] * n_regs
         self.bufs: List = bufs if bufs is not None else [None] * n_bufs
-        # staged D2H boxes: (host slice tuple, device payload, codec|None)
+        # staged D2H boxes: (host slice tuple, device payload, codec|None),
+        # and the chunk of each (labels its pull in the trace)
         self.staged: List[tuple] = []
+        self.staged_chunks: List[int] = []
         # reg slot -> (payload, shape, dtype) between a non-identity
         # Compress(h2d) and its Decompress
         self.wire: Dict[int, tuple] = {}
 
     def commit(self) -> None:
-        for _, rows, _ in self.staged:
-            jax.block_until_ready(rows)
-        for sl, rows, codec_name in self.staged:
-            rows = np.asarray(rows)
+        """Drain the device, then pull each staged box over the link and
+        scatter it into the host array, each phase its own span."""
+        if not self.staged:
+            return
+        sp = self.spans
+        with sp.span("HostCommit.drain"):
+            for _, rows, _ in self.staged:
+                jax.block_until_ready(rows)
+        for (sl, rows, codec_name), chunk in zip(self.staged,
+                                                 self.staged_chunks):
+            sp.meta[sp.site] = chunk
+            with sp.span("D2H.pull"):
+                rows = np.asarray(rows)
             if codec_name is not None:
                 # the wire round trip: device-side encode, host-side decode
-                codec = get_codec(codec_name)
-                rows = codec.decode(codec.encode(rows), rows.shape, rows.dtype)
-            self.host[sl] = rows
+                with sp.span("D2H.decode"):
+                    codec = get_codec(codec_name)
+                    rows = codec.decode(codec.encode(rows), rows.shape,
+                                        rows.dtype)
+            with sp.span("D2H.scatter"):
+                self.host[sl] = rows
         self.staged.clear()
+        self.staged_chunks.clear()
 
     def commit_round(self, rnd: int) -> None:
         """A round's HostCommit barrier: drain staged writes, record the
@@ -405,15 +521,21 @@ class CompiledPlan:
         }
 
     def runtime(self, x: np.ndarray,
-                slot_pool: Optional[SlotPool] = None) -> _Runtime:
+                slot_pool: Optional[SlotPool] = None,
+                spans: Optional[SpanRecorder] = None) -> _Runtime:
         """Build the slot-indexed runtime for one run, leasing slot
         storage from ``slot_pool`` when given (release it back with
-        :meth:`release_runtime` when the run retires)."""
-        host = validate_domain(self.plan, x)
-        if slot_pool is None:
-            return _Runtime(host, self.n_reg_slots, self.n_buf_slots)
-        regs, bufs = slot_pool.acquire(self.n_reg_slots, self.n_buf_slots)
-        return _Runtime(host, self.n_reg_slots, self.n_buf_slots, regs, bufs)
+        :meth:`release_runtime` when the run retires).  The run's spans
+        go to ``spans`` (a fresh :class:`SpanRecorder` by default),
+        starting with the domain's copy, ``Execute.validate``."""
+        spans = spans if spans is not None else SpanRecorder()
+        with spans.span("Execute.validate"):
+            host = validate_domain(self.plan, x)
+        regs = bufs = None
+        if slot_pool is not None:
+            regs, bufs = slot_pool.acquire(self.n_reg_slots, self.n_buf_slots)
+        return _Runtime(host, self.n_reg_slots, self.n_buf_slots, regs, bufs,
+                        spans)
 
     @staticmethod
     def release_runtime(rt: _Runtime,
@@ -445,8 +567,7 @@ class CompiledPlan:
         leak pool occupancy)."""
         rt = self.runtime(x, slot_pool)
         rt.on_commit = on_commit
-        wall = [0.0] * len(OP_TAGS)
-        counts = [0] * len(OP_TAGS)
+        rec = rt.spans
         hits0, miss0 = self.cache.hits, self.cache.misses
         f0 = injector.faults_injected if injector is not None else 0
         r0 = injector.retries if injector is not None else 0
@@ -454,13 +575,7 @@ class CompiledPlan:
         t_run = perf()
 
         def run(ops: Tuple[BoundOp, ...]) -> None:
-            for tag, fn, rnd, chunk in ops:
-                if injector is not None:
-                    consult(injector, retry, rnd, chunk, OP_TAGS[tag])
-                t0 = perf()
-                fn(rt)
-                wall[tag] += perf() - t0
-                counts[tag] += 1
+            rec.run(ops, rt, injector, retry)
 
         stages = self.stages
         try:
@@ -493,11 +608,9 @@ class CompiledPlan:
         finally:
             self.release_runtime(rt, slot_pool)
 
-        stats = ExecStats(
+        stats = rec.exec_stats(
+            "FusedKernel",
             kernel_impl=self.kernel_impl,
-            op_counts={OP_TAGS[i]: c for i, c in enumerate(counts) if c},
-            op_wall_s={OP_TAGS[i]: wall[i] for i, c in enumerate(counts) if c},
-            kernel_calls=counts[_TAG["FusedKernel"]],
             shape_buckets=self.shape_buckets,
             kernel_compiles=self.cache.misses - miss0,
             kernel_cache_hits=self.cache.hits - hits0,
@@ -614,12 +727,17 @@ def _bind_kernel(slot: int, op: FusedKernel, bucket_h: int, impl_name: str,
     def run(rt):
         cache.lookup(key, lambda: fn)
         band = rt.regs[slot]
+        sp = rt.spans
         if pad:
-            z = jnp.zeros((pad, band.shape[1]), band.dtype)
-            band = jnp.concatenate([z, band] if pad_top else [band, z], axis=0)
-        out = fn(band, name, steps, keep_top=kt, keep_bottom=kb)
+            with sp.span("FusedKernel.pad"):
+                z = jnp.zeros((pad, band.shape[1]), band.dtype)
+                band = jnp.concatenate([z, band] if pad_top else [band, z],
+                                       axis=0)
+        with sp.span("FusedKernel.call"):
+            out = fn(band, name, steps, keep_top=kt, keep_bottom=kb)
         if pad:
-            out = out[out.shape[0] - h_out:] if pad_top else out[:h_out]
+            with sp.span("FusedKernel.crop"):
+                out = out[out.shape[0] - h_out:] if pad_top else out[:h_out]
         rt.regs[slot] = out
 
     return run
@@ -640,8 +758,9 @@ def _bind_kernel_nd(slot: int, op: FusedKernel, cache: KernelCache,
 
     def run(rt):
         cache.lookup(key, lambda: multi_step_box)
-        rt.regs[slot] = multi_step_box(rt.regs[slot], name, steps,
-                                       keep_lo=kl, keep_hi=kh)
+        with rt.spans.span("FusedKernel.call"):
+            rt.regs[slot] = multi_step_box(rt.regs[slot], name, steps,
+                                           keep_lo=kl, keep_hi=kh)
 
     return run
 
@@ -675,7 +794,8 @@ def _bind_kernel_masked(slot: int, op: FusedKernel, box: Box,
 
     def run(rt):
         fn = cache.lookup(key, make)
-        rt.regs[slot] = fn(rt.regs[slot], oy, ox)
+        with rt.spans.span("FusedKernel.call"):
+            rt.regs[slot] = fn(rt.regs[slot], oy, ox)
 
     return run
 
@@ -866,10 +986,12 @@ def lower(plan: ExecutionPlan, policy=None, fused_step=None,
             codec_name = pending_d2h.pop(op.reg, None)
             rsl, hsl = op.reg_box.slices(), op.box.slices()
 
-            def run(rt, _s=slot, _rsl=rsl, _hsl=hsl, _codec=codec_name):
+            def run(rt, _s=slot, _rsl=rsl, _hsl=hsl, _codec=codec_name,
+                    _c=op.chunk):
                 band = rt.regs[_s]
                 rt.regs[_s] = None
                 rt.staged.append((_hsl, band[_rsl], _codec))
+                rt.staged_chunks.append(_c)
 
             emit(key, "D2H", run)
         else:  # pragma: no cover - planner/lowering version skew
@@ -916,20 +1038,33 @@ class _ShardRuntime:
     slice.  ``slot_pool`` (optional) is the shared pool hierarchical
     inner plans lease their chunk-slot storage from."""
 
-    __slots__ = ("host", "bands", "mail", "staged", "slot_pool")
+    __slots__ = ("host", "bands", "mail", "staged", "slot_pool", "spans")
 
-    def __init__(self, host: np.ndarray, n_slots: int, slot_pool=None):
+    def __init__(self, host: np.ndarray, n_slots: int, slot_pool=None,
+                 spans: Optional[SpanRecorder] = None):
         self.host = host
         self.bands: List = [None] * n_slots
         self.mail: Dict[tuple, jnp.ndarray] = {}
-        self.staged: List[tuple] = []   # (host slice tuple, device band)
+        # (host slice tuple, device band, (round, rank) of the store)
+        self.staged: List[tuple] = []
         self.slot_pool = slot_pool
+        self.spans = spans if spans is not None else SpanRecorder("rank")
 
     def commit(self) -> None:
-        for _, rows in self.staged:
-            jax.block_until_ready(rows)
-        for sl, rows in self.staged:
-            self.host[sl] = np.asarray(rows)
+        """The end-of-plan barrier, in the phases of
+        :meth:`_Runtime.commit`."""
+        if not self.staged:
+            return
+        sp = self.spans
+        with sp.span("HostCommit.drain"):
+            for _, rows, _ in self.staged:
+                jax.block_until_ready(rows)
+        for sl, rows, site in self.staged:
+            sp.at(*site)
+            with sp.span("D2H.pull"):
+                rows = np.asarray(rows)
+            with sp.span("D2H.scatter"):
+                self.host[sl] = rows
         self.staged.clear()
 
 
@@ -1001,6 +1136,9 @@ class CompiledShardedPlan:
     cache: KernelCache
     lower_s: float
     kernel_impl: str = "shard_sim"
+    # op classes timed without a span of their own: a hierarchical
+    # ShardKernel runs a nested plan, whose own spans are the leaves
+    phased: frozenset = _PHASED
 
     def describe(self) -> dict:
         return {
@@ -1034,10 +1172,11 @@ class CompiledShardedPlan:
         the pool and releases it when the nested run retires (also on
         fault paths — the inner executor releases in ``finally``), so
         :meth:`SlotPool.assert_balanced` holds after any exit."""
-        rt = _ShardRuntime(validate_domain(self.plan, x), self.n_slots,
-                           slot_pool=slot_pool)
-        wall = [0.0] * len(OP_TAGS)
-        counts = [0] * len(OP_TAGS)
+        rec = SpanRecorder("rank", self.phased)
+        with rec.span("Execute.validate"):
+            host = validate_domain(self.plan, x)
+        rt = _ShardRuntime(host, self.n_slots, slot_pool=slot_pool,
+                           spans=rec)
         hits0, miss0 = self.cache.hits, self.cache.misses
         f0 = injector.faults_injected if injector is not None else 0
         r0 = injector.retries if injector is not None else 0
@@ -1045,13 +1184,7 @@ class CompiledShardedPlan:
         t_run = perf()
         try:
             for stage in self.stages:
-                for tag, fn, rnd, rank in stage.ops:
-                    if injector is not None:
-                        consult(injector, retry, rnd, rank, OP_TAGS[tag])
-                    t0 = perf()
-                    fn(rt)
-                    wall[tag] += perf() - t0
-                    counts[tag] += 1
+                rec.run(stage.ops, rt, injector, retry)
             rt.commit()
         except InjectedFault as f:
             from .recovery import PlanExecutionError, plan_fingerprint
@@ -1060,11 +1193,9 @@ class CompiledShardedPlan:
                 f"op={f.op_class}: {f.kind}",
                 fault=f, last_committed_round=-1,
                 fingerprint=plan_fingerprint(self.plan)) from f
-        stats = ExecStats(
+        stats = rec.exec_stats(
+            "ShardKernel",
             kernel_impl=self.kernel_impl,
-            op_counts={OP_TAGS[i]: c for i, c in enumerate(counts) if c},
-            op_wall_s={OP_TAGS[i]: wall[i] for i, c in enumerate(counts) if c},
-            kernel_calls=counts[_TAG["ShardKernel"]],
             shape_buckets=self.shape_buckets,
             kernel_compiles=self.cache.misses - miss0,
             kernel_cache_hits=self.cache.hits - hits0,
@@ -1242,10 +1373,10 @@ def lower_sharded(plan,
                 slot = regs.free(f"band:{op.rank}", ordinal)
                 sl = op.box.slices()
 
-                def run(rt, _s=slot, _sl=sl):
+                def run(rt, _s=slot, _sl=sl, _site=(op.round, op.rank)):
                     band = rt.bands[_s]
                     rt.bands[_s] = None
-                    rt.staged.append((_sl, band))
+                    rt.staged.append((_sl, band, _site))
 
                 bound.append((_TAG["ShardStore"], run, op.round, op.rank))
             else:  # pragma: no cover - planner/lowering version skew
@@ -1260,4 +1391,6 @@ def lower_sharded(plan,
         cache=cache,
         lower_s=time.perf_counter() - t0,
         kernel_impl="shard_sim+hier" if hplan is not None else "shard_sim",
+        phased=(_PHASED | {_TAG["ShardKernel"]}) if hplan is not None
+        else _PHASED,
     )

@@ -30,14 +30,12 @@ import numpy as np
 
 from repro.core.analytic import Hardware
 from repro.core.autotune import pipeline_makespan, stage_costs
-from repro.core.faults import InjectedFault, consult
-from repro.core.lower import CompiledPlan, ExecStats, OP_TAGS, SlotPool
+from repro.core.faults import InjectedFault
+from repro.core.lower import CompiledPlan, ExecStats, SlotPool, SpanRecorder
 from repro.core.recovery import PlanExecutionError
 
 __all__ = ["ScheduledJob", "admission_order", "interleave_stages",
            "modeled_makespan", "run_interleaved"]
-
-_KERNEL_TAG = OP_TAGS.index("FusedKernel")
 
 
 @dataclasses.dataclass
@@ -124,7 +122,9 @@ def run_interleaved(jobs: Sequence[ScheduledJob],
     sequence, so job B's H2D is issued while job A's kernels are still
     in flight — the cross-job analogue of the paper's N_strm = 3
     overlap.  Latency is stamped when a job's last stage retires (its
-    final barrier has drained its staged writes).
+    final barrier has drained its staged writes).  Each job's ops are
+    timed by its own :class:`~repro.core.lower.SpanRecorder`, whose
+    profiler spans carry ``job`` beside ``run``, ``round`` and ``chunk``.
 
     Graceful degradation: a job whose injector raises a terminal fault
     is *isolated* — its leased slots are released on the spot, its
@@ -134,16 +134,14 @@ def run_interleaved(jobs: Sequence[ScheduledJob],
     survivors stay bit-identical to a fault-free run)."""
     perf = time.perf_counter
     runtimes = {}
+    recs = {j.job_id: SpanRecorder(job=j.job_id) for j in jobs}
     try:
         for job in jobs:
-            runtimes[job.job_id] = job.compiled.runtime(job.x, slot_pool)
+            runtimes[job.job_id] = job.compiled.runtime(
+                job.x, slot_pool, spans=recs[job.job_id])
         merged = interleave_stages(jobs)
         n = len(merged)
         prefetched = [False] * n
-        wall: Dict[int, List[float]] = {
-            j.job_id: [0.0] * len(OP_TAGS) for j in jobs}
-        counts: Dict[int, List[int]] = {
-            j.job_id: [0] * len(OP_TAGS) for j in jobs}
         snap: Dict[int, Tuple[int, int]] = {}   # job -> (hits, misses) deltas
         inj0: Dict[int, Tuple[int, int]] = {}   # job -> (faults, retries) at t0
         for j in jobs:
@@ -156,18 +154,10 @@ def run_interleaved(jobs: Sequence[ScheduledJob],
 
         def run(job: ScheduledJob, ops) -> None:
             rt = runtimes[job.job_id]
-            w, c = wall[job.job_id], counts[job.job_id]
             cache = job.compiled.cache
             h0, m0 = cache.snapshot()
             try:
-                for tag, fn, rnd, chunk in ops:
-                    if job.injector is not None:
-                        consult(job.injector, job.retry, rnd, chunk,
-                                OP_TAGS[tag])
-                    t0 = perf()
-                    fn(rt)
-                    w[tag] += perf() - t0
-                    c[tag] += 1
+                recs[job.job_id].run(ops, rt, job.injector, job.retry)
             finally:
                 h1, m1 = cache.snapshot()
                 dh, dm = snap[job.job_id]
@@ -217,19 +207,16 @@ def run_interleaved(jobs: Sequence[ScheduledJob],
 
         out = []
         for job in jobs:
-            c, w = counts[job.job_id], wall[job.job_id]
             dh, dm = snap[job.job_id]
             if job.injector is not None:
                 df = job.injector.faults_injected - inj0[job.job_id][0]
                 dr = job.injector.retries - inj0[job.job_id][1]
             else:
                 df = dr = 0
-            stats = ExecStats(
+            stats = recs[job.job_id].exec_stats(
+                "FusedKernel",
                 executor="pipelined",
                 kernel_impl=job.compiled.kernel_impl,
-                op_counts={OP_TAGS[i]: v for i, v in enumerate(c) if v},
-                op_wall_s={OP_TAGS[i]: w[i] for i, v in enumerate(c) if v},
-                kernel_calls=c[_KERNEL_TAG],
                 shape_buckets=job.compiled.shape_buckets,
                 kernel_compiles=dm,
                 kernel_cache_hits=dh,

@@ -1,0 +1,273 @@
+"""The executor's spans (:class:`repro.core.lower.SpanRecorder`): each
+bound op and each phase of the fused step and of the barrier is one
+flat profiler host event carrying ``run``, ``round`` and its site, and
+its host seconds and count land in ``ExecStats`` under the same name.
+
+The solves are traced with JAX's own profiler on the CPU and read back
+from the ``.xplane.pb`` it writes, as the benchmark reads a chip's."""
+import glob
+import json
+from collections import Counter
+
+import jax
+import numpy as np
+
+from repro import compile_plan, get_stencil
+from repro.core.executor import DoubleBufferedExecutor
+from repro.core.lower import lower
+from repro.kernels.dispatch import DispatchPolicy
+from repro.serve.scheduler import ScheduledJob, run_interleaved
+
+from _subproc import run_fake_device_subprocess
+
+BARRIER = ("HostCommit.drain", "D2H.pull", "D2H.scatter")
+
+
+def _solve(Y=62, X=40, n=8, d=3, k_off=4, k_on=2, seed=0):
+    """An SO2DR solve of 2 rounds over 3 chunks whose bands pad up to a
+    shape bucket."""
+    plan = compile_plan("so2dr", get_stencil("box2d1r"), Y, X, n, d, k_off,
+                        k_on)
+    x = np.random.default_rng(seed).random((Y, X), dtype=np.float32)
+    return plan, x
+
+
+def _traced(tmp_path, fn):
+    """Run ``fn`` under the profiler; return the program's spans in
+    start order as ``(name, start_ns, end_ns, stats)``: the host events
+    that carry a ``run`` stat."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    assert len(paths) == 1, paths
+    return _program_spans(ProfileData.from_file(paths[0]).planes)
+
+
+def _program_spans(planes):
+    spans = []
+    for p in planes:
+        if p.name != "/host:CPU":
+            continue
+        for line in p.lines:
+            for e in line.events:
+                stats = dict(e.stats)
+                if "run" in stats:
+                    spans.append((e.name, e.start_ns,
+                                  e.start_ns + e.duration_ns, stats))
+    return sorted(spans, key=lambda s: s[1])
+
+
+def _assert_flat(spans):
+    """No program span's interval contains (or overlaps) another's."""
+    for a, b in zip(spans, spans[1:]):
+        assert a[2] <= b[1], f"{a[0]} {a[3]} overlaps {b[0]} {b[3]}"
+
+
+def _expected(plan, site="chunk"):
+    """The leaf spans a solve of ``plan`` makes, by ``(name, round,
+    site)``, pad and crop aside."""
+    exp = Counter({("Execute.validate", -1, -1): 1})
+    for op in plan.ops:
+        name = type(op).__name__
+        if name == "HostCommit":
+            exp["HostCommit.drain", op.round, -1] += 1
+            continue
+        at = (op.round, getattr(op, site))
+        span = "FusedKernel.call" if name == "FusedKernel" else name
+        exp[(span,) + at] += 1
+        if name == "D2H":
+            exp[("D2H.pull",) + at] += 1
+            exp[("D2H.scatter",) + at] += 1
+    return exp
+
+
+def _found(spans, site="chunk"):
+    return Counter((n, s["round"], s[site]) for n, _, _, s in spans
+                   if n not in ("FusedKernel.pad", "FusedKernel.crop"))
+
+
+def _check_padding(spans):
+    """Pad and crop come in pairs, each at the site of a fused call."""
+    calls = {(s["round"], s["chunk"]) for n, _, _, s in spans
+             if n == "FusedKernel.call"}
+    pads = Counter((s["round"], s["chunk"]) for n, _, _, s in spans
+                   if n == "FusedKernel.pad")
+    crops = Counter((s["round"], s["chunk"]) for n, _, _, s in spans
+                    if n == "FusedKernel.crop")
+    assert pads and pads == crops
+    assert set(pads) <= calls
+
+
+def _check_stats(es, plan):
+    counts = plan.op_counts()
+    assert set(es.op_wall_s) == set(es.op_counts)
+    assert sum(es.op_wall_s[k] for k in BARRIER) <= es.op_wall_s["HostCommit"]
+    assert es.op_counts["FusedKernel.call"] == counts["FusedKernel"]
+    assert es.kernel_calls == counts["FusedKernel"]
+    assert es.op_counts["D2H.pull"] == es.op_counts["D2H.scatter"] \
+        == counts["D2H"]
+    assert es.op_counts["HostCommit.drain"] == counts["HostCommit"]
+    assert es.op_counts["Execute.validate"] == 1
+
+
+def test_double_buffered_solve_spans(tmp_path):
+    plan, x = _solve()
+    counts = plan.op_counts()
+    assert counts["HostCommit"] >= 2 and counts["H2D"] >= 3
+    ex = DoubleBufferedExecutor(policy=DispatchPolicy())
+    want, _ = ex.execute(plan, x)      # compiles outside the trace
+    spans = _traced(tmp_path, lambda: ex.execute(plan, x))
+    _assert_flat(spans)
+    assert len({s["run"] for _, _, _, s in spans}) == 1
+    assert _found(spans) == _expected(plan)
+    _check_padding(spans)
+    # the op classes with phases are counted, but make no span of their own
+    traced = ex.exec_stats
+    assert traced.op_counts == dict(
+        Counter(n for n, _, _, _ in spans)
+        + Counter(FusedKernel=counts["FusedKernel"],
+                  HostCommit=counts["HostCommit"]))
+
+    got, _ = ex.execute(plan, x)       # no profiler running
+    np.testing.assert_array_equal(got, want)
+    _check_stats(ex.exec_stats, plan)
+    assert ex.exec_stats.op_counts == traced.op_counts
+    assert ex.exec_stats.op_wall_s["HostCommit"] <= ex.exec_stats.wall_s
+
+
+def test_interleaved_jobs_spans(tmp_path):
+    plans = [_solve(), _solve(Y=66, X=48, seed=1)]
+    compiled = [lower(p) for p, _ in plans]
+    jobs = [ScheduledJob(job_id=10 + i, compiled=c, x=x, predicted_s=0.0)
+            for i, (c, (_, x)) in enumerate(zip(compiled, plans))]
+    run_interleaved(jobs)              # compiles outside the trace
+    spans = _traced(tmp_path, lambda: run_interleaved(jobs))
+    _assert_flat(spans)
+    runs = set()
+    for job, (plan, _) in zip(jobs, plans):
+        mine = [s for s in spans if s[3]["job"] == job.job_id]
+        assert len({s["run"] for _, _, _, s in mine}) == 1
+        runs |= {s["run"] for _, _, _, s in mine}
+        assert _found(mine) == _expected(plan)
+        _check_padding(mine)
+    assert len(runs) == 2
+    assert {s["job"] for _, _, _, s in spans} == {10, 11}
+
+    for (job, host, stats, _, fault), (plan, _) in zip(run_interleaved(jobs),
+                                                        plans):
+        assert fault is None and host is not None
+        _check_stats(stats, plan)
+
+
+SHARDED = """
+import json
+import jax
+import numpy as np
+from repro.core.executor import ShardedSimExecutor
+from repro.core.shard import compile_sharded
+
+assert len(jax.devices()) == 4
+plan = compile_sharded("box2d1r", 48, 48, 4, 2, (2, 2))
+x = np.random.default_rng(0).random((48, 48), dtype=np.float32)
+ex = ShardedSimExecutor()
+ex.execute(plan, x)
+untraced = ex.exec_stats
+jax.profiler.start_trace({out!r})
+ex.execute(plan, x)
+jax.profiler.stop_trace()
+ops = [(type(op).__name__, op.round, op.rank)
+       for _, phase in plan.phases() for op in phase]
+with open({out!r} + "/result.json", "w") as f:
+    json.dump({{"op_counts": untraced.op_counts,
+               "op_wall_s": untraced.op_wall_s,
+               "kernel_calls": untraced.kernel_calls, "ops": ops}}, f)
+print("SHARDED_OK")
+"""
+
+
+def test_sharded_sim_spans(tmp_path):
+    from jax.profiler import ProfileData
+
+    out = str(tmp_path)
+    run_fake_device_subprocess(SHARDED.format(out=out), "SHARDED_OK",
+                               n_devices=4)
+    res = json.load(open(f"{out}/result.json"))
+    paths = glob.glob(f"{out}/**/*.xplane.pb", recursive=True)
+    spans = _program_spans(ProfileData.from_file(paths[0]).planes)
+    _assert_flat(spans)
+    assert len({s["run"] for _, _, _, s in spans}) == 1
+    exp = Counter({("Execute.validate", -1, -1): 1})
+    for name, rnd, rank in res["ops"]:
+        exp[name, rnd, rank] += 1
+        if name == "ShardStore":
+            exp["D2H.pull", rnd, rank] += 1
+            exp["D2H.scatter", rnd, rank] += 1
+    drain = [s for s in spans if s[0] == "HostCommit.drain"]
+    assert len(drain) == 1      # one barrier, at the end of the plan
+    assert _found([s for s in spans if s[0] != "HostCommit.drain"],
+                  "rank") == exp
+
+    counts, wall = res["op_counts"], res["op_wall_s"]
+    assert set(wall) == set(counts)
+    assert counts == dict(Counter(n for n, _, _, _ in spans))
+    stores = sum(1 for name, _, _ in res["ops"] if name == "ShardStore")
+    assert counts["D2H.pull"] == counts["D2H.scatter"] == stores == 4
+    assert res["kernel_calls"] == counts["ShardKernel"] > 0
+
+
+def test_recorder_meta_and_counts():
+    """Each recorder is a run of its own; a span counts under its name."""
+    from repro.core.lower import SpanRecorder
+
+    a, b = SpanRecorder(), SpanRecorder(job=3)
+    assert a.meta["run"] != b.meta["run"]
+    assert b.meta == {"run": b.meta["run"], "round": -1, "chunk": -1,
+                      "job": 3}
+    with a.span("D2H.pull"):
+        pass
+    a.at(2, 5)
+    assert a.meta["round"] == 2 and a.meta["chunk"] == 5
+    assert dict(a.count) == {"D2H.pull": 1} and not b.count
+    assert a.wall["D2H.pull"] >= 0.0
+
+
+def test_codec_barrier_decodes_in_its_own_span(tmp_path):
+    from repro.core.executor import EagerExecutor
+
+    plan = compile_plan("so2dr", get_stencil("box2d1r"), 62, 40, 8, 3, 4, 2,
+                        codec="zrle")
+    x = np.random.default_rng(2).random((62, 40), dtype=np.float32)
+    ex = EagerExecutor(policy=DispatchPolicy())
+    ex.execute(plan, x)
+    spans = _traced(tmp_path, lambda: ex.execute(plan, x))
+    _assert_flat(spans)
+    n = Counter(name for name, _, _, _ in spans)
+    assert n["D2H.decode"] == n["D2H.pull"] == plan.op_counts()["D2H"]
+    assert n["Compress"] == plan.op_counts()["Compress"]
+
+
+def test_hierarchical_shard_kernel_leaves_spans_to_its_nested_plan(tmp_path):
+    """A hierarchical ShardKernel runs a nested plan; it is timed but
+    makes no span, so the nested plan's spans stay leaves."""
+    from repro.core.executor import ShardedSimExecutor
+    from repro.core.hierarchy import compile_hierarchical
+
+    plan = compile_hierarchical("star2d1r", 48, 48, 8, 2, (2, 2),
+                                inner_engine="so2dr", inner_d=3)
+    x = np.random.default_rng(3).random((48, 48), dtype=np.float32)
+    ex = ShardedSimExecutor()
+    ex.execute(plan, x)
+    spans = _traced(tmp_path, lambda: ex.execute(plan, x))
+    _assert_flat(spans)
+    names = Counter(n for n, _, _, _ in spans)
+    es = ex.exec_stats
+    assert "ShardKernel" not in names and es.op_counts["ShardKernel"] > 0
+    # each ShardKernel ran one nested plan: a run of its own, in chunks
+    inner = {s["run"] for n, _, _, s in spans if "chunk" in s}
+    assert len(inner) == es.op_counts["ShardKernel"]
+    assert names["FusedKernel.call"] > 0
