@@ -6,10 +6,11 @@ Three interpreters of the same op schedule:
   pre-refactor engine behavior bit-for-bit against the oracle.
 * :class:`DoubleBufferedExecutor` — software-pipelined: chunk ``i+1``'s
   H2D is issued while chunk ``i``'s kernels/D2H are still in flight
-  (JAX async dispatch carries the overlap; nothing blocks until a
-  ``HostCommit`` barrier forces the staged device handles with
-  ``jax.block_until_ready``).  This is the paper's multi-stream overlap
-  (Sec. II, N_strm = 3), previously impossible with inline engine loops.
+  (JAX async dispatch carries the overlap; the issuing thread blocks
+  only at a ``HostCommit`` barrier, and a streamed D2H box waits for
+  the device on a write-back thread).  This is the paper's
+  multi-stream overlap (Sec. II, N_strm = 3), previously impossible
+  with inline engine loops.
 * :class:`DryRunExecutor` — walks no device work at all and returns the
   plan-derived :class:`TransferStats`; the autotuner costs the whole
   configuration sweep with it.  It also costs multi-device

@@ -32,9 +32,14 @@ compiles the plan once instead:
   alongside wall-clock per span name.  The d=8, 4-round SO2DR config
   compiles at most one kernel per shape bucket instead of one per
   chunk x round.
+* **streamed write-back** — a large D2H box that no later host read of
+  its round touches is pulled and scattered into the host array by a
+  small write-back pool as soon as it is ready, under the next chunks'
+  kernels; the round's barrier then waits only for what is still in
+  flight and for the boxes held back (:func:`_streamed_d2h`).
 * **spans** — one :class:`SpanRecorder` per run times every bound op
-  (and every phase of the fused step and the barrier) into
-  :class:`ExecStats` and writes each as a flat profiler host event
+  (and every phase of the fused step, the barrier and a write-back)
+  into :class:`ExecStats` and writes each as a flat profiler host event
   carrying ``run``, ``round`` and ``chunk``, on the device trace's clock.
 
 Accounting is untouched: :meth:`CompiledPlan.execute` still returns the
@@ -50,6 +55,8 @@ import itertools
 import threading
 import time
 from collections import defaultdict
+from concurrent import futures
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -89,6 +96,15 @@ BoundOp = Tuple[int, Callable, int, int]
 _PHASED = frozenset((_TAG["FusedKernel"], _TAG["HostCommit"]))
 # one per process, so no two runs in one trace share a ``run`` id
 _RUN_IDS = itertools.count(1)
+# write-back pool threads per run: one box of a 49152^2 box2d1r solve
+# takes about 2.8 s to pull and scatter on a v5e host, a chunk's kernels
+# about 1.6 s, so two keep up with the device
+WRITEBACK_THREADS = 2
+# smallest D2H box worth a write-back thread: below about a MiB, handing
+# the box to the pool and waking the barrier cost more than the pull and
+# scatter it would hide (a 2-round SO2DR solve on the CPU backend: 3 KB
+# and 88 KB boxes slower streamed, 1 MiB even, 4 MiB faster)
+STREAM_MIN_BYTES = 1 << 20
 
 
 @dataclasses.dataclass
@@ -99,11 +115,12 @@ class ExecStats:
     ``op_counts``/``op_wall_s`` are keyed by span name
     (:class:`SpanRecorder`): every op class, plus the phases
     ``FusedKernel.{pad,call,crop}``, ``HostCommit.drain``,
-    ``D2H.{pull,decode,scatter}`` and ``Execute.validate`` (outside
-    ``wall_s``).  Wall-clock numbers are host-observed dispatch+compute
-    time — meaningful for comparing executors/kernels on one machine,
-    never for gating CI (the cache/op counters are the deterministic
-    part)."""
+    ``D2H.{wait,pull,decode,scatter}`` (``D2H.wait`` on streamed boxes
+    only; their spans are timed on the write-back threads) and
+    ``Execute.validate`` (outside ``wall_s``).  Wall-clock numbers are
+    host-observed dispatch+compute time — meaningful for comparing
+    executors/kernels on one machine, never for gating CI (the cache/op
+    counters are the deterministic part)."""
 
     executor: str = ""
     kernel_impl: str = ""
@@ -219,6 +236,24 @@ class SpanRecorder:
             else:
                 with _Span(self, name):
                     fn(rt)
+
+    def fork(self, rnd: int, site: int) -> "SpanRecorder":
+        """A recorder for work on another thread: the same ``run`` and
+        fixed meta, pinned at ``(rnd, site)``, with tallies of its own
+        (merged back by :meth:`absorb` on the issuing thread)."""
+        child = SpanRecorder.__new__(SpanRecorder)
+        child.site, child.phased = self.site, self.phased
+        child.meta = {**self.meta, "round": rnd, self.site: site}
+        child.wall = defaultdict(float)
+        child.count = defaultdict(int)
+        return child
+
+    def absorb(self, other: "SpanRecorder") -> None:
+        """Add a forked recorder's seconds and counts to this one's."""
+        for k, v in other.wall.items():
+            self.wall[k] += v
+        for k, v in other.count.items():
+            self.count[k] += v
 
     def exec_stats(self, kernel_op: str, **fields) -> ExecStats:
         """An :class:`ExecStats` of this run's spans; ``kernel_calls``
@@ -393,13 +428,46 @@ class SlotPool:
                     f"{self.leases - self.in_use} released)")
 
 
+def _pull_scatter(host: np.ndarray, sl, rows, codec_name: Optional[str],
+                  rec: SpanRecorder) -> None:
+    """Pull one staged box over the link, decode it if a codec carried
+    it, and scatter it into the host array, each phase a span on
+    ``rec``."""
+    with rec.span("D2H.pull"):
+        rows = np.asarray(rows)
+    if codec_name is not None:
+        # the wire round trip: device-side encode, host-side decode
+        with rec.span("D2H.decode"):
+            codec = get_codec(codec_name)
+            rows = codec.decode(codec.encode(rows), rows.shape, rows.dtype)
+    with rec.span("D2H.scatter"):
+        host[sl] = rows
+
+
+def _write_back(host: np.ndarray, sl, rows, codec_name: Optional[str],
+                rec: SpanRecorder) -> None:
+    """A streamed box's write-back, on a pool thread: wait for the box on
+    the device (``D2H.wait``), then pull, decode and scatter it.  The
+    device array and its pulled copy die with this call."""
+    with rec.span("D2H.wait"):
+        jax.block_until_ready(rows)
+    _pull_scatter(host, sl, rows, codec_name, rec)
+
+
 class _Runtime:
     """Slot-indexed register/buffer/staging state the bound closures run
     against (the lowered counterpart of the executors' old name-keyed
-    device state)."""
+    device state).
 
-    __slots__ = ("host", "regs", "bufs", "staged", "staged_chunks", "wire",
-                 "on_commit", "committed_round", "spans")
+    Write-back: a D2H box that the plan lets stream (:func:`_streamed_d2h`)
+    is committed as soon as it is staged, to a pool of
+    ``WRITEBACK_THREADS`` threads that write it into the host array
+    under the next chunks' kernels; a held box waits for the round's
+    barrier, as does the barrier for every write-back of the round."""
+
+    __slots__ = ("host", "regs", "bufs", "staged", "staged_sites", "held",
+                 "pool", "writebacks", "wire", "on_commit",
+                 "committed_round", "spans")
 
     def __init__(self, host: np.ndarray, n_regs: int, n_bufs: int,
                  regs: Optional[List] = None, bufs: Optional[List] = None,
@@ -414,48 +482,94 @@ class _Runtime:
         # needed — closures only ever index their bound slots)
         self.regs: List = regs if regs is not None else [None] * n_regs
         self.bufs: List = bufs if bufs is not None else [None] * n_bufs
-        # staged D2H boxes: (host slice tuple, device payload, codec|None),
-        # and the chunk of each (labels its pull in the trace)
+        # D2H boxes being committed: (host slice tuple, device payload,
+        # codec|None), and the (round, chunk, streams) of each; boxes held
+        # for the barrier as (box, site) pairs
         self.staged: List[tuple] = []
-        self.staged_chunks: List[int] = []
+        self.staged_sites: List[tuple] = []
+        self.held: List[tuple] = []
+        # the write-back pool (made on the first streamed box) and its
+        # write-backs in flight: (future, forked SpanRecorder)
+        self.pool: Optional[ThreadPoolExecutor] = None
+        self.writebacks: List[tuple] = []
         # reg slot -> (payload, shape, dtype) between a non-identity
         # Compress(h2d) and its Decompress
         self.wire: Dict[int, tuple] = {}
 
+    def stage(self, box: tuple, site: tuple) -> None:
+        """A D2H box leaves its register: committed at once if it streams
+        (``site[2]``), else held for the barrier."""
+        if site[2]:
+            self.staged.append(box)
+            self.staged_sites.append(site)
+            self.commit()
+        else:
+            self.held.append((box, site))
+
     def commit(self) -> None:
-        """Drain the device, then pull each staged box over the link and
-        scatter it into the host array, each phase its own span."""
-        if not self.staged:
-            return
+        """Commit the staged boxes: a streamed box goes to the write-back
+        pool, which waits for it on the device; a held box (staged by
+        :meth:`barrier` once the device is drained) is pulled and
+        scattered here, each phase its own span."""
         sp = self.spans
-        with sp.span("HostCommit.drain"):
+        for (sl, rows, codec_name), (rnd, chunk, streams) in zip(
+                self.staged, self.staged_sites):
+            if streams:
+                if self.pool is None:
+                    self.pool = ThreadPoolExecutor(
+                        WRITEBACK_THREADS, thread_name_prefix="writeback")
+                rec = sp.fork(rnd, chunk)
+                self.writebacks.append((self.pool.submit(
+                    _write_back, self.host, sl, rows, codec_name, rec), rec))
+            else:
+                sp.at(rnd, chunk)
+                _pull_scatter(self.host, sl, rows, codec_name, sp)
+        self.staged.clear()
+        self.staged_sites.clear()
+
+    def barrier(self) -> None:
+        """Write back every box not yet in the host array.
+        ``HostCommit.drain`` waits, on this thread, for the held boxes on
+        the device and for the write-backs in flight (raising the first
+        one's exception); the held boxes are then committed here."""
+        if not self.held and not self.writebacks:
+            return
+        for box, site in self.held:
+            self.staged.append(box)
+            self.staged_sites.append(site)
+        self.held.clear()
+        with self.spans.span("HostCommit.drain"):
             for _, rows, _ in self.staged:
                 jax.block_until_ready(rows)
-        for (sl, rows, codec_name), chunk in zip(self.staged,
-                                                 self.staged_chunks):
-            sp.meta[sp.site] = chunk
-            with sp.span("D2H.pull"):
-                rows = np.asarray(rows)
-            if codec_name is not None:
-                # the wire round trip: device-side encode, host-side decode
-                with sp.span("D2H.decode"):
-                    codec = get_codec(codec_name)
-                    rows = codec.decode(codec.encode(rows), rows.shape,
-                                        rows.dtype)
-            with sp.span("D2H.scatter"):
-                self.host[sl] = rows
-        self.staged.clear()
-        self.staged_chunks.clear()
+            done, self.writebacks = self.writebacks, []
+            futures.wait([f for f, _ in done])
+            for _, rec in done:
+                self.spans.absorb(rec)
+            for f, _ in done:
+                f.result()
+        self.commit()
 
     def commit_round(self, rnd: int) -> None:
-        """A round's HostCommit barrier: drain staged writes, record the
-        round as the recovery point, fire the checkpoint hook (the host
-        array is the complete machine state here — nothing else survives
-        a barrier)."""
-        self.commit()
+        """A round's HostCommit barrier: write back the round's boxes,
+        record the round as the recovery point, fire the checkpoint hook
+        (the host array is the complete machine state here — nothing
+        else survives a barrier)."""
+        self.barrier()
         self.committed_round = rnd
         if self.on_commit is not None:
             self.on_commit(rnd, self.host)
+
+    def close(self) -> None:
+        """Stop the write-back pool (write-backs not yet started are
+        cancelled, running ones finish) and drop every staged box, so
+        no thread and no device buffer outlives the run."""
+        if self.pool is not None:
+            self.pool.shutdown(wait=True, cancel_futures=True)
+            self.pool = None
+        self.writebacks.clear()
+        self.staged.clear()
+        self.staged_sites.clear()
+        self.held.clear()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -540,6 +654,9 @@ class CompiledPlan:
     @staticmethod
     def release_runtime(rt: _Runtime,
                         slot_pool: Optional[SlotPool]) -> None:
+        """Retire a run: stop its write-back pool, then give its slot
+        storage back to ``slot_pool``."""
+        rt.close()
         if slot_pool is not None:
             slot_pool.release(rt.regs, rt.bufs)
 
@@ -562,9 +679,12 @@ class CompiledPlan:
         terminal faults surface as a typed
         :class:`repro.core.recovery.PlanExecutionError` carrying the
         last committed round.  ``on_commit(round, host)`` fires after
-        every round's barrier drains — the checkpoint hook.  Leased slot
-        storage is released on *every* exit path (faulted runs do not
-        leak pool occupancy)."""
+        every round's barrier drains — the checkpoint hook.  A streamed
+        D2H box is written back on a pool thread under the next chunks'
+        kernels; a write-back's exception is raised at the next barrier.
+        Leased slot storage is released and the write-back pool stopped
+        on *every* exit path (faulted runs do not leak pool occupancy or
+        threads)."""
         rt = self.runtime(x, slot_pool)
         rt.on_commit = on_commit
         rec = rt.spans
@@ -596,7 +716,7 @@ class CompiledPlan:
                         run(stages[j + 1].prefetch)
                         prefetched[j + 1] = True
                     run(stage.rest if prefetched[j] else stage.ops)
-            rt.commit()   # no-op unless a planner forgot the final barrier
+            rt.barrier()  # no-op unless a planner forgot the final barrier
         except InjectedFault as f:
             from .recovery import PlanExecutionError, plan_fingerprint
             raise PlanExecutionError(
@@ -800,6 +920,42 @@ def _bind_kernel_masked(slot: int, op: FusedKernel, box: Box,
     return run
 
 
+def _overlaps(a: Box, b: Box) -> bool:
+    return all(alo < bhi and blo < ahi
+               for alo, ahi, blo, bhi in zip(a.lo, a.hi, b.lo, b.hi))
+
+
+def _streamed_d2h(ops) -> set:
+    """The ids of the D2H ops whose boxes stream: written back as soon as
+    they are ready instead of at their round's barrier.
+
+    A box streams when it holds at least ``STREAM_MIN_BYTES``, a
+    FusedKernel of its round comes after it in plan order (there is
+    device work to hide the write-back under) and no host read of its
+    round that comes after it (an H2D, or a host-side Compress) reads
+    rows that intersect it.  A later read therefore
+    always sees the rows as they were before the round; prefetching only
+    moves reads earlier, so the rule holds under ``pipeline=True``.
+    Host reads that come before the box belong to chunks whose input the
+    in-order device has consumed by the time the box is ready."""
+    streamed = set()
+    reads: List[Box] = []           # host boxes read later in the round
+    kernel_after = False
+    for op in reversed(ops):
+        if isinstance(op, HostCommit):
+            reads, kernel_after = [], False
+        elif isinstance(op, H2D) or (isinstance(op, Compress)
+                                     and op.direction == "h2d"):
+            reads.append(op.box)
+        elif isinstance(op, FusedKernel):
+            kernel_after = True
+        elif (isinstance(op, D2H) and op.nbytes >= STREAM_MIN_BYTES
+              and kernel_after
+              and not any(_overlaps(op.box, b) for b in reads)):
+            streamed.add(id(op))
+    return streamed
+
+
 def lower(plan: ExecutionPlan, policy=None, fused_step=None,
           kernel_cache: Optional[KernelCache] = None,
           bucket_registry: Optional[BucketRegistry] = None,
@@ -841,6 +997,7 @@ def lower(plan: ExecutionPlan, policy=None, fused_step=None,
     pending_h2d: Dict[str, str] = {}    # reg -> codec (non-identity, h2d)
     pending_d2h: Dict[str, str] = {}    # reg -> codec (non-identity, d2h)
 
+    streamed = _streamed_d2h(plan.ops)
     signatures = set()
     stages: List[List] = []             # [key, [BoundOp...]]
     chunk_ordinal = -1                  # index of the current chunk stage
@@ -900,7 +1057,7 @@ def lower(plan: ExecutionPlan, policy=None, fused_step=None,
 
                 emit(key, "Decompress", run)
             else:
-                # d2h decode runs at the HostCommit barrier (the first
+                # d2h decode runs at the box's write-back (the first
                 # point the device bytes are forced anyway)
                 emit(key, "Decompress", _noop)
         elif isinstance(op, H2D):
@@ -985,13 +1142,13 @@ def lower(plan: ExecutionPlan, policy=None, fused_step=None,
                 reg_boxes.pop(op.reg, None)
             codec_name = pending_d2h.pop(op.reg, None)
             rsl, hsl = op.reg_box.slices(), op.box.slices()
+            site = (op.round, op.chunk, id(op) in streamed)
 
             def run(rt, _s=slot, _rsl=rsl, _hsl=hsl, _codec=codec_name,
-                    _c=op.chunk):
+                    _site=site):
                 band = rt.regs[_s]
                 rt.regs[_s] = None
-                rt.staged.append((_hsl, band[_rsl], _codec))
-                rt.staged_chunks.append(_c)
+                rt.stage((_hsl, band[_rsl], _codec), _site)
 
             emit(key, "D2H", run)
         else:  # pragma: no cover - planner/lowering version skew

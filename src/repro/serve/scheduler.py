@@ -202,7 +202,7 @@ def run_interleaved(jobs: Sequence[ScheduledJob],
                             prefetched[m + 1] = True
                 try_run(job, stage.rest if prefetched[m] else stage.ops)
             if job.job_id not in failed and s == last_stage[job.job_id]:
-                runtimes[job.job_id].commit()   # planner-forgot-barrier no-op
+                runtimes[job.job_id].barrier()  # planner-forgot-barrier no-op
                 latency[job.job_id] = perf() - t_start
 
         out = []

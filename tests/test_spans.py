@@ -1,26 +1,37 @@
 """The executor's spans (:class:`repro.core.lower.SpanRecorder`): each
-bound op and each phase of the fused step and of the barrier is one
-flat profiler host event carrying ``run``, ``round`` and its site, and
-its host seconds and count land in ``ExecStats`` under the same name.
+bound op and each phase of the fused step, of the barrier and of a
+streamed write-back is one profiler host event carrying ``run``,
+``round`` and its site, flat on its thread, and its host seconds and
+count land in ``ExecStats`` under the same name.
 
 The solves are traced with JAX's own profiler on the CPU and read back
 from the ``.xplane.pb`` it writes, as the benchmark reads a chip's."""
 import glob
+import importlib
 import json
 from collections import Counter
 
 import jax
 import numpy as np
+import pytest
 
 from repro import compile_plan, get_stencil
 from repro.core.executor import DoubleBufferedExecutor
-from repro.core.lower import lower
+from repro.core.lower import _streamed_d2h, lower
 from repro.kernels.dispatch import DispatchPolicy
 from repro.serve.scheduler import ScheduledJob, run_interleaved
 
 from _subproc import run_fake_device_subprocess
 
 BARRIER = ("HostCommit.drain", "D2H.pull", "D2H.scatter")
+
+
+@pytest.fixture(autouse=True)
+def stream_small_boxes(monkeypatch):
+    """The solves here are small: boxes of any size may stream, so the
+    write-back threads' spans are made."""
+    monkeypatch.setattr(importlib.import_module("repro.core.lower"),
+                        "STREAM_MIN_BYTES", 0)
 
 
 def _solve(Y=62, X=40, n=8, d=3, k_off=4, k_on=2, seed=0):
@@ -49,28 +60,36 @@ def _traced(tmp_path, fn):
 
 
 def _program_spans(planes):
+    """The spans as ``(name, start_ns, end_ns, stats)``; ``stats`` also
+    holds the index of the thread's line under ``"line"``."""
     spans = []
     for p in planes:
         if p.name != "/host:CPU":
             continue
-        for line in p.lines:
+        for i, line in enumerate(p.lines):
             for e in line.events:
                 stats = dict(e.stats)
                 if "run" in stats:
+                    stats["line"] = i
                     spans.append((e.name, e.start_ns,
                                   e.start_ns + e.duration_ns, stats))
     return sorted(spans, key=lambda s: s[1])
 
 
 def _assert_flat(spans):
-    """No program span's interval contains (or overlaps) another's."""
-    for a, b in zip(spans, spans[1:]):
-        assert a[2] <= b[1], f"{a[0]} {a[3]} overlaps {b[0]} {b[3]}"
+    """On each thread, no program span's interval contains (or
+    overlaps) another's."""
+    for line in {s["line"] for *_, s in spans}:
+        mine = [sp for sp in spans if sp[3]["line"] == line]
+        for a, b in zip(mine, mine[1:]):
+            assert a[2] <= b[1], f"{a[0]} {a[3]} overlaps {b[0]} {b[3]}"
 
 
 def _expected(plan, site="chunk"):
     """The leaf spans a solve of ``plan`` makes, by ``(name, round,
-    site)``, pad and crop aside."""
+    site)``, pad and crop aside: a streamed D2H box waits for the device
+    in a ``D2H.wait`` of its own, a held one in the barrier's drain."""
+    streamed = _streamed_d2h(plan.ops)
     exp = Counter({("Execute.validate", -1, -1): 1})
     for op in plan.ops:
         name = type(op).__name__
@@ -83,7 +102,16 @@ def _expected(plan, site="chunk"):
         if name == "D2H":
             exp[("D2H.pull",) + at] += 1
             exp[("D2H.scatter",) + at] += 1
+            if id(op) in streamed:
+                exp[("D2H.wait",) + at] += 1
     return exp
+
+
+def _on_issuing_thread(spans):
+    """The spans on the thread that ran the plan's ops (the one with the
+    domain's copy)."""
+    line = next(s["line"] for n, _, _, s in spans if n == "Execute.validate")
+    return [sp for sp in spans if sp[3]["line"] == line]
 
 
 def _found(spans, site="chunk"):
@@ -105,14 +133,19 @@ def _check_padding(spans):
 
 def _check_stats(es, plan):
     counts = plan.op_counts()
+    n_streamed = len(_streamed_d2h(plan.ops))
     assert set(es.op_wall_s) == set(es.op_counts)
-    assert sum(es.op_wall_s[k] for k in BARRIER) <= es.op_wall_s["HostCommit"]
     assert es.op_counts["FusedKernel.call"] == counts["FusedKernel"]
     assert es.kernel_calls == counts["FusedKernel"]
     assert es.op_counts["D2H.pull"] == es.op_counts["D2H.scatter"] \
         == counts["D2H"]
+    assert es.op_counts.get("D2H.wait", 0) == n_streamed
     assert es.op_counts["HostCommit.drain"] == counts["HostCommit"]
     assert es.op_counts["Execute.validate"] == 1
+    if not n_streamed:
+        # every box is written back in the barrier, on the issuing thread
+        assert sum(es.op_wall_s[k] for k in BARRIER) \
+            <= es.op_wall_s["HostCommit"]
 
 
 def test_double_buffered_solve_spans(tmp_path):
@@ -132,12 +165,36 @@ def test_double_buffered_solve_spans(tmp_path):
         Counter(n for n, _, _, _ in spans)
         + Counter(FusedKernel=counts["FusedKernel"],
                   HostCommit=counts["HostCommit"]))
+    # the streamed write-backs run off the issuing thread, which keeps
+    # only the held boxes' pulls
+    streamed = len(_streamed_d2h(plan.ops))
+    assert streamed == 2 * counts["HostCommit"]
+    issuing = Counter(n for n, *_ in _on_issuing_thread(spans))
+    assert "D2H.wait" not in issuing
+    assert issuing["D2H.pull"] == counts["D2H"] - streamed
 
     got, _ = ex.execute(plan, x)       # no profiler running
     np.testing.assert_array_equal(got, want)
     _check_stats(ex.exec_stats, plan)
     assert ex.exec_stats.op_counts == traced.op_counts
     assert ex.exec_stats.op_wall_s["HostCommit"] <= ex.exec_stats.wall_s
+
+
+def test_held_boxes_write_back_inside_the_barrier(tmp_path):
+    """``naive_tb`` re-reads each box's rows for the next chunk, so every
+    box is held for the barrier, which pulls and scatters them on the
+    issuing thread, inside its own seconds."""
+    plan = compile_plan("naive_tb", get_stencil("box2d1r"), 62, 40, 8, 3,
+                        4, 2)
+    x = np.random.default_rng(4).random((62, 40), dtype=np.float32)
+    assert not _streamed_d2h(plan.ops)
+    ex = DoubleBufferedExecutor(policy=DispatchPolicy())
+    ex.execute(plan, x)
+    spans = _traced(tmp_path, lambda: ex.execute(plan, x))
+    _assert_flat(spans)
+    assert _on_issuing_thread(spans) == spans
+    assert _found(spans) == _expected(plan)
+    _check_stats(ex.exec_stats, plan)
 
 
 def test_interleaved_jobs_spans(tmp_path):
