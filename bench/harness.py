@@ -1,13 +1,24 @@
 """One run of one cell: set-up, the measured window, and the check.
 
-The window drives the system's own path, ``compile_plan(engine, ...)``
-into ``DoubleBufferedExecutor(policy=DispatchPolicy()).execute(plan,
-x)``, whole solves back to back in a closed loop, one at a time, each
-from the host array in to the host array out.  It stops at the first
-solve that ends after ``seconds``.  Set-up, which :func:`run_cell`
-times from the process start its caller gives, makes the domain from
-the seed and warms every shape up by running one round on the fewest
-leading rows of it whose plan has every op shape of the solve.
+The window drives the system's own path, whole solves back to back in a
+closed loop, one at a time, each from the host array in to the host
+array out.  It stops at the first solve that ends after ``seconds``,
+and holds at least the configuration's ``min_window_solves`` (1 where
+it gives none).  The configuration's engine picks the path from
+:data:`PATHS`:
+
+* an engine of ``compile_plan`` (one chip): ``compile_plan(engine,
+  ...)`` into ``DoubleBufferedExecutor(policy=DispatchPolicy())``;
+  set-up warms every shape up by running one round on the fewest
+  leading rows of the domain whose plan has every op shape of the solve.
+* ``sharded`` (a mesh of chips): ``compile_sharded(...)`` into
+  ``ShardMapExecutor`` on a mesh of the cell's devices.  The solve is
+  one program, which the executor traces anew at every solve and loads
+  from the persistent compilation cache; set-up compiles that program
+  once, for the sharded domain, so that it is in the cache.
+
+Set-up, which :func:`run_cell` times from the process start its caller
+gives, makes the domain from the seed and runs that warm-up.
 """
 from __future__ import annotations
 
@@ -17,18 +28,22 @@ import sys
 import tempfile
 import time
 from collections import defaultdict
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
+from repro.core.oocore import ENGINES
 
 from bench import peaks as peaks_mod
-from bench import reference
+from bench import reference, work
 from bench.spec import Cell, read_metrics
 from bench.trace import TraceSummary, reduce_trace
-from bench.workload import SolveParams, make_domain, solve_params
+from bench.workload import SolveParams, make_domain
 
 # compile events are JAX's own monitoring events under this prefix
 COMPILE_EVENTS = "/jax/core/compile/"
+# the persistent compilation cache's hits and misses
+CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                "/jax/compilation_cache/cache_misses": "misses"}
 WINDOW_SPAN = "bench_window"
 
 
@@ -45,6 +60,8 @@ class CompileLog:
         self.phase = "setup"
         self.events: Dict[str, Dict[tuple, List[float]]] = defaultdict(
             lambda: defaultdict(lambda: [0, 0.0]))
+        self.cache: Dict[str, Dict[str, int]] = defaultdict(
+            lambda: defaultdict(int))
 
     def _on(self, event: str, duration: float, **kw) -> None:
         if event.startswith(COMPILE_EVENTS):
@@ -53,12 +70,18 @@ class CompileLog:
             slot[0] += 1
             slot[1] += duration
 
+    def _on_cache(self, event: str, **kw) -> None:
+        if event in CACHE_EVENTS:
+            self.cache[self.phase][CACHE_EVENTS[event]] += 1
+
     def __enter__(self) -> "CompileLog":
         jax.monitoring.register_event_duration_secs_listener(self._on)
+        jax.monitoring.register_event_listener(self._on_cache)
         return self
 
     def __exit__(self, *exc) -> None:
         jax.monitoring.unregister_event_duration_listener(self._on)
+        jax.monitoring.unregister_event_listener(self._on_cache)
 
     def count(self, phase: str) -> int:
         return sum(c for c, _ in self.events[phase].values())
@@ -70,6 +93,9 @@ class CompileLog:
             f"{total!r} s")
         for (event, fun), (n, s) in rows:
             log(f"  compile {phase} {event} {fun} n={n} s={s!r}")
+        cache = self.cache[phase]
+        log(f"persistent compilation cache in {phase}: "
+            f"hits={cache['hits']} misses={cache['misses']}")
 
 
 @dataclasses.dataclass
@@ -78,7 +104,7 @@ class Context:
 
     cell: Cell
     params: SolveParams
-    plan: object                 # the solve's ExecutionPlan
+    plan: object                 # the solve's ExecutionPlan or ShardedPlan
     solves: int                  # whole solves in the window
     exec_wall_s: float           # sum of ExecStats.wall_s over them
     op_wall_s: Dict[str, float]  # sum of ExecStats.op_wall_s over them
@@ -96,6 +122,51 @@ class Context:
         from repro.core.plan import FusedKernel
 
         return [op for op in self.plan.ops if isinstance(op, FusedKernel)]
+
+    @property
+    def kernel_work(self) -> List[work.Work]:
+        """Useful work of each kernel call of one solve, on every chip
+        (:mod:`bench.work`)."""
+        return solve_path(self.params.engine).kernel_work(
+            self.plan, self.params, int(self.cell.config["flops_per_cell"]))
+
+
+def solve_params(config: dict) -> SolveParams:
+    """One solve of the configuration, its schedule read by its
+    engine's path; raises ``KeyError`` for an unknown engine or a
+    schedule that lacks a key the engine needs, and ``ValueError`` for
+    steps that are not whole rounds."""
+    engine = config["engine"]
+    path = solve_path(engine)
+    sched = config["schedule"]
+    missing = [k for k in path.schedule if k not in sched]
+    if missing:
+        raise KeyError(f"the schedule lacks {missing} for engine {engine!r}")
+    r = int(config["radius"])
+    side = int(config["interior"]) + 2 * r
+    common = dict(engine=engine, stencil=config["stencil"], radius=r,
+                  Y=side, X=side, steps=int(config["steps_per_solve"]))
+    p = path.params(common, sched)
+    rounds_of = getattr(p, path.rounds_of)
+    if p.steps % rounds_of:
+        raise ValueError(f"{p.steps} steps are not whole rounds of "
+                         f"{path.rounds_of}={rounds_of}")
+    return p
+
+
+def check_cell(cell: Cell) -> SolveParams:
+    """Exit non-zero, before any set-up, where the cell cannot run as
+    its configuration says: an unknown engine, a schedule its engine
+    cannot take, or a mesh of another size than the cell's chips."""
+    try:
+        p = solve_params(cell.config)
+    except (KeyError, ValueError) as e:
+        sys.exit(f"bench: {cell.name}: configuration {cell.config_name}: "
+                 f"{e.args[0]}")
+    if p.chips != cell.chips:
+        sys.exit(f"bench: {cell.name} asks for {cell.chips} chip(s); its "
+                 f"configuration's solve runs on {p.chips}")
+    return p
 
 
 def check_device(chips: int) -> None:
@@ -119,10 +190,13 @@ def _device_info(chips: int) -> dict:
     return {"platform": d.platform, "kind": d.device_kind, "count": chips}
 
 
-def _memory_peak() -> Optional[int]:
-    stats = jax.devices()[0].memory_stats() or {}
-    peak = stats.get("peak_bytes_in_use")
-    return int(peak) if peak is not None else None
+def _memory_peaks(chips: int) -> List[Optional[int]]:
+    """``peak_bytes_in_use`` of each of the cell's devices."""
+    out = []
+    for d in jax.devices()[:chips]:
+        peak = (d.memory_stats() or {}).get("peak_bytes_in_use")
+        out.append(int(peak) if peak is not None else None)
+    return out
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
@@ -134,11 +208,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     file: the reference in the precision below the configuration's)
     takes the program's place in the check, and no window runs: its
     ``correct`` has to come out false."""
-    from repro import compile_plan, get_stencil
-    from repro.core.executor import DoubleBufferedExecutor
-    from repro.kernels.dispatch import DispatchPolicy
+    from repro import get_stencil
 
-    p = solve_params(cell.config)
+    p = check_cell(cell)
+    path = solve_path(p.engine)
     st = get_stencil(p.stencil)
     if st.radius != p.radius:
         raise ValueError(f"config radius {p.radius} is not the program's "
@@ -149,28 +222,22 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                         float(cell.traffic["high"]))
         log(f"setup: domain {p.Y}x{p.X} f32 made in "
             f"{time.perf_counter() - t0!r} s")
-        plan = compile_plan(p.engine, st, p.Y, p.X, p.steps, p.d, p.s_tb,
-                            p.k_on)
+        plan = path.compile(p, st)
         if control:
             result = {"correct": None, "attempted": 1, "failed": 0,
                       "metrics": {}, "device": _device_info(cell.chips)}
             return _check(result, cell, p, plan, x, None, seed, log,
                           cell.config["control"])
-        warm, warm_rows = warm_up_plan(p, st, plan)
-        ex = DoubleBufferedExecutor(policy=DispatchPolicy())
+        ex = path.executor(p)
         t0 = time.perf_counter()
-        ex.execute(warm, x[:warm_rows])
-        es = ex.exec_stats
-        log(f"setup: warm-up of {warm.op_counts().get('HostCommit', 0)} "
-            f"round(s) on {warm_rows}x{p.X} in {warm.d} chunk(s) "
-            f"{time.perf_counter() - t0!r} s, kernel_impl="
-            f"{es.kernel_impl} kernel_compiles={es.kernel_compiles} "
-            f"shape_buckets={es.shape_buckets}")
-        del warm
+        what = path.warm_up(p, st, plan, ex, x)
+        log(f"setup: warm-up of {what} {time.perf_counter() - t0!r} s"
+            + _exec_note(ex.exec_stats))
         setup_s = time.perf_counter() - t_start
         clog.report("setup", log)
 
         clog.phase = "window"
+        min_solves = int(cell.config.get("min_window_solves", 1))
         tracer = _Tracer() if trace else None
         solve_s: List[float] = []
         op_wall: Dict[str, float] = defaultdict(float)
@@ -189,14 +256,18 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 exec_wall += es.wall_s
                 for k, v in es.op_wall_s.items():
                     op_wall[k] += v
-                if time.perf_counter() - t_w0 >= seconds:
+                if (time.perf_counter() - t_w0 >= seconds
+                        and len(solve_s) >= min_solves):
                     break
         window_s = time.perf_counter() - t_w0
         if tracer:
             tracer.stop()
         clog.report("window", log)
         window_compiles = clog.count("window")
-    memory_peak = _memory_peak()
+    peaks = _memory_peaks(cell.chips)
+    known = [v for v in peaks if v is not None]
+    memory_peak = max(known) if known else None
+    log(f"memory: peak_bytes_in_use of each device {peaks!r}")
     del ex
     shown = solve_s if len(solve_s) <= 20 else (
         [min(solve_s), statistics.median(solve_s), max(solve_s)])
@@ -219,7 +290,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     else:
         row = peaks_mod.lookup(device["kind"])
         f32 = peaks_mod.measure_f32_flops(log)
-        summary = tracer.reduce(cell.config["kernel_pattern"], log)
+        summary = tracer.reduce(cell.config["kernel_pattern"], log,
+                                cell.config.get("collective_pattern"))
         tracer.close()
         ctx = Context(cell=cell, params=p, plan=plan, solves=len(solve_s),
                       exec_wall_s=exec_wall, op_wall_s=dict(op_wall),
@@ -239,9 +311,9 @@ def _check(result: dict, cell: Cell, p: SolveParams, plan, x, out, seed: int,
     place), with the reference; set ``correct``, ``failed`` and, last,
     ``checks`` in ``result``."""
     t0 = time.perf_counter()
-    chunk_starts = _chunk_starts(plan)
-    wins = reference.windows((p.Y, p.X), p.radius, chunk_starts, seed,
-                             size=reference.window_size((p.Y, p.X)))
+    wins = reference.windows((p.Y, p.X), p.radius, seed=seed,
+                             size=reference.window_size((p.Y, p.X)),
+                             **solve_path(p.engine).seams(plan))
     numbers = reference.compare(out, x, cell.config, p.steps, wins, control)
     limits = cell.config["limits"]
     checks = {k: {"value": v, "limit": limits[k]} for k, v in numbers.items()}
@@ -256,6 +328,14 @@ def _check(result: dict, cell: Cell, p: SolveParams, plan, x, out, seed: int,
     for k, v in checks.items():
         log(f"check {k} value={v['value']!r} limit={v['limit']!r}")
     return result
+
+
+def _exec_note(es) -> str:
+    """The executor's kernel notes after a warm-up that executed."""
+    if es is None:
+        return ""
+    return (f", kernel_impl={es.kernel_impl} kernel_compiles="
+            f"{es.kernel_compiles} shape_buckets={es.shape_buckets}")
 
 
 def _shape_key(op) -> tuple:
@@ -308,6 +388,140 @@ def _chunk_starts(plan) -> List[int]:
     return sorted(starts)
 
 
+def _plan_params(common: dict, sched: dict) -> SolveParams:
+    return SolveParams(**common, d=int(sched["d"]), s_tb=int(sched["s_tb"]),
+                       k_on=int(sched["k_on"]))
+
+
+def _compile_plan(p: SolveParams, st):
+    from repro import compile_plan
+
+    return compile_plan(p.engine, st, p.Y, p.X, p.steps, p.d, p.s_tb,
+                        p.k_on)
+
+
+def _plan_executor(_: SolveParams):
+    from repro.core.executor import DoubleBufferedExecutor
+    from repro.kernels.dispatch import DispatchPolicy
+
+    return DoubleBufferedExecutor(policy=DispatchPolicy())
+
+
+def _warm_up_plan(p: SolveParams, st, plan, ex, x) -> str:
+    warm, rows = warm_up_plan(p, st, plan)
+    ex.execute(warm, x[:rows])
+    return (f"{warm.op_counts().get('HostCommit', 0)} round(s) on "
+            f"{rows}x{p.X} in {warm.d} chunk(s)")
+
+
+def _plan_work(plan, p: SolveParams, flops_per_cell: int) -> List[work.Work]:
+    from repro.core.plan import FusedKernel
+
+    return work.fused_work([op for op in plan.ops
+                            if isinstance(op, FusedKernel)],
+                           p.radius, flops_per_cell, plan.itemsize)
+
+
+def _mesh_params(common: dict, sched: dict) -> SolveParams:
+    mesh = tuple(int(n) for n in sched["mesh"])
+    if len(mesh) != 2 or min(mesh) < 1:
+        raise ValueError(f"mesh {list(mesh)} is not two positive sizes")
+    return SolveParams(**common, mesh=mesh, k_ici=int(sched["k_ici"]))
+
+
+def _compile_sharded(p: SolveParams, st):
+    from repro.core.shard import compile_sharded
+
+    return compile_sharded(st, p.Y, p.X, p.steps, p.k_ici, p.mesh)
+
+
+def _mesh_executor(p: SolveParams):
+    """``ShardMapExecutor`` on a mesh of the first ``p.chips`` devices,
+    rows over ``data`` and columns over ``model``."""
+    from jax.sharding import AxisType
+    from repro.core.executor import ShardMapExecutor
+
+    mesh = jax.make_mesh(p.mesh, ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=jax.devices()[:p.chips])
+    return ShardMapExecutor(mesh=mesh)
+
+
+def _warm_up_mesh(p: SolveParams, st, plan, ex, x) -> str:
+    """Compile the shard program the executor builds for ``plan``, for
+    ``x`` sharded as the executor puts it, into the persistent cache;
+    the window's solves trace it anew and load it from there."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.core.distributed import distributed_stencil_step_fn
+
+    fn = distributed_stencil_step_fn(plan.stencil, plan.k_ici, plan.n,
+                                     ex.mesh, ex.row_axis, ex.col_axis)
+    sharding = NamedSharding(ex.mesh, PartitionSpec(ex.row_axis,
+                                                    ex.col_axis))
+    fn.lower(jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                  sharding=sharding)).compile()
+    return (f"the shard program (compiled, not run), {plan.rounds} round(s) "
+            f"of k_ici={plan.k_ici} on {p.Y}x{p.X} over a "
+            f"{p.mesh[0]}x{p.mesh[1]} mesh")
+
+
+def _mesh_seams(plan) -> dict:
+    """First row and first column of each shard after the first in its
+    mesh axis: the places where halos cross between chips."""
+    return {"chunk_starts": (),
+            "row_seams": sorted({sh.y0 for sh in plan.shards if sh.y0}),
+            "col_seams": sorted({sh.x0 for sh in plan.shards if sh.x0})}
+
+
+def _mesh_work(plan, p: SolveParams, flops_per_cell: int) -> List[work.Work]:
+    from repro.core.plan import ShardKernel
+
+    return [work.shard_work(op.gy0, op.gx0, op.h, op.w, p.Y, p.X, p.radius,
+                            op.steps, flops_per_cell, plan.itemsize)
+            for stream in plan.streams for op in stream
+            if isinstance(op, ShardKernel)]
+
+
+@dataclasses.dataclass(frozen=True)
+class SolvePath:
+    """What one kind of plan needs of a run."""
+
+    schedule: Tuple[str, ...]  # the schedule keys the engine needs
+    rounds_of: str             # the one whose whole multiples steps are
+    params: Callable           # (common params, schedule) -> SolveParams
+    compile: Callable          # (params, stencil) -> plan
+    executor: Callable         # (params) -> executor
+    # (params, stencil, plan, executor, domain) -> what it did: has every
+    # program of the solve compiled and loaded before the window
+    warm_up: Callable
+    seams: Callable            # (plan) -> reference.windows' fixed places
+    kernel_work: Callable      # (plan, params, flops/cell) -> [Work]
+
+
+PLAN_PATH = SolvePath(
+    schedule=("d", "s_tb", "k_on"), rounds_of="s_tb", params=_plan_params,
+    compile=_compile_plan, executor=_plan_executor, warm_up=_warm_up_plan,
+    seams=lambda plan: {"chunk_starts": _chunk_starts(plan)},
+    kernel_work=_plan_work)
+
+SHARDED_PATH = SolvePath(
+    schedule=("mesh", "k_ici"), rounds_of="k_ici", params=_mesh_params,
+    compile=_compile_sharded, executor=_mesh_executor, warm_up=_warm_up_mesh,
+    seams=_mesh_seams, kernel_work=_mesh_work)
+
+# engine name -> path: every single-device engine of compile_plan, and
+# the plan sharded over a chip mesh
+PATHS = {**{name: PLAN_PATH for name in ENGINES}, "sharded": SHARDED_PATH}
+
+
+def solve_path(engine: str) -> SolvePath:
+    """The path of a configuration's ``engine``; ``KeyError`` when no
+    path runs it."""
+    if engine not in PATHS:
+        raise KeyError(f"unknown engine {engine!r}; known: {sorted(PATHS)}")
+    return PATHS[engine]
+
+
 class _Tracer:
     """The profiler around the window, writing under ``TMPDIR``."""
 
@@ -323,18 +537,24 @@ class _Tracer:
     def stop(self) -> None:
         jax.profiler.stop_trace()
 
-    def reduce(self, kernel_pattern: str, log) -> TraceSummary:
+    def reduce(self, kernel_pattern: str, log,
+               collective_pattern: Optional[str] = None) -> TraceSummary:
         import glob
 
         paths = glob.glob(f"{self.dir.name}/**/*.xplane.pb", recursive=True)
         if len(paths) != 1:
             raise RuntimeError(f"expected one trace file, found {paths}")
         t0 = time.perf_counter()
-        summary = reduce_trace(paths[0], WINDOW_SPAN, kernel_pattern)
+        summary = reduce_trace(paths[0], WINDOW_SPAN, kernel_pattern,
+                               collective_pattern)
         log(f"trace: read in {time.perf_counter() - t0!r} s; "
             f"window_s={summary.window_s!r} busy_s={summary.busy_s!r} "
             f"kernel_s={summary.kernel_s!r} kernel_calls="
             f"{summary.kernel_calls} device_lines={summary.lines}")
+        if collective_pattern:
+            log(f"trace: collective_s={summary.collective_s!r} "
+                f"collective_calls={summary.collective_calls} "
+                f"({collective_pattern!r})")
         return summary
 
     def close(self) -> None:

@@ -95,26 +95,44 @@ def region_origin(y0: int, size: int, margin: int, extent: int) -> int:
 
 
 def windows(shape: Tuple[int, int], radius: int, chunk_starts: Sequence[int],
-            seed: int, n_random: int = 4,
-            size: int = WINDOW) -> List[Tuple[str, int, int]]:
+            seed: int, n_random: int = 4, size: int = WINDOW,
+            row_seams: Sequence[int] = (),
+            col_seams: Sequence[int] = ()) -> List[Tuple[str, int, int]]:
     """The windows the check compares: ``(label, y0, x0)``.
 
     Fixed places first: both corners, where the held frame is; every
     chunk boundary, where region sharing hands rows from one chunk to
     the next; the domain's middle across a 512-column kernel tile
-    seam.  Then ``n_random`` windows drawn from the seed."""
+    seam.  Then ``n_random`` windows drawn from the seed.  Last, the
+    seams between the shards of a mesh, where halos are exchanged:
+    each row seam (the first row of a shard below another) at a drawn
+    column, each column seam at a drawn row, and each point where a
+    row seam crosses a column seam."""
     Y, X = shape
     rng = np.random.default_rng(np.random.SeedSequence([int(seed), 0x5EED]))
+
+    def across(b: int, extent: int) -> int:
+        return min(max(b - size // 2, 0), extent - size)
+
     out = [("corner", 0, 0), ("far-corner", Y - size, X - size)]
     for b in chunk_starts:
         x0 = int(rng.integers(0, X - size + 1))
-        out.append((f"chunk-boundary@{b}", min(max(b - size // 2, 0), Y - size),
-                    x0))
+        out.append((f"chunk-boundary@{b}", across(b, Y), x0))
     seam = max(min((X // 2) // 512 * 512 - size // 2, X - size), 0)
     out.append(("tile-seam", max(Y // 2 - size // 2, 0), seam))
     for i in range(n_random):
         out.append((f"random{i}", int(rng.integers(0, Y - size + 1)),
                     int(rng.integers(0, X - size + 1))))
+    for b in row_seams:
+        out.append((f"row-seam@{b}", across(b, Y),
+                    int(rng.integers(0, X - size + 1))))
+    for b in col_seams:
+        out.append((f"col-seam@{b}", int(rng.integers(0, Y - size + 1)),
+                    across(b, X)))
+    for by in row_seams:
+        for bx in col_seams:
+            out.append((f"seam-cross@{by},{bx}", across(by, Y),
+                        across(bx, X)))
     return out
 
 

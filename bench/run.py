@@ -17,9 +17,10 @@ precision below the configuration's) in the program's place and runs no
 window: its ``correct`` has to come out false.  It is for setting and
 re-reading the limits; the benchmark's own runs leave it at 0.
 
-Without a chip whose ``device_kind`` is in ``bench/peaks.json``, or with
-fewer chips than the cell asks for, it exits non-zero and prints no
-result line.  It never falls back to the CPU.
+Without a chip whose ``device_kind`` is in ``bench/peaks.json``, with
+fewer chips than the cell asks for, or with a configuration whose solve
+runs on another number of chips than the cell asks for, it exits
+non-zero and prints no result line.  It never falls back to the CPU.
 """
 from __future__ import annotations
 

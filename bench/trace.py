@@ -16,6 +16,9 @@ and device events share one clock.
   matches ``kernel_pattern`` (a regular expression; the fused-step
   programs, their pad and crop included), clipped to the window and
   averaged over the chips.
+* collective time, where a ``collective_pattern`` is given: the union
+  of the ``XLA Ops`` events whose HLO name matches it (a regular
+  expression), clipped to the window, averaged over the chips.
 * device ops: the ``XLA Ops`` events by module and HLO name, the ten
   that took most time.
 * idle gaps: the ten longest intervals of the window with no module
@@ -47,6 +50,9 @@ class TraceSummary:
     top_ops: List[list]
     idle_gaps: List[list]
     lines: List[str]
+    # with a collective pattern: seconds and events of the matching ops
+    collective_s: Optional[float] = None
+    collective_calls: Optional[int] = None
 
 
 def _clip(start: float, end: float, win: Interval) -> Optional[Interval]:
@@ -125,13 +131,15 @@ def _name_gap(gap: Interval, host) -> str:
     return best
 
 
-def reduce_planes(planes, window_span: str,
-                  kernel_pattern: str) -> TraceSummary:
+def reduce_planes(planes, window_span: str, kernel_pattern: str,
+                  collective_pattern: Optional[str] = None) -> TraceSummary:
     """:func:`reduce_trace` on planes already read (``ProfileData``'s)."""
     planes = list(planes)
     win = _window(planes, window_span)
     kernel = re.compile(kernel_pattern)
+    collective = re.compile(collective_pattern) if collective_pattern else None
     busy_ns, kernel_ns, calls = [], [], 0
+    coll_ns, coll_calls = [], 0
     ops_ns: Dict[str, float] = defaultdict(float)
     lines: List[str] = []
     first_busy: List[Interval] = []
@@ -155,13 +163,19 @@ def reduce_planes(planes, window_span: str,
         kernel_ns.append(sum(e - s for s, e in k))
         calls += len(k)
         starts = [iv[0] for _, iv in clipped]
+        coll: List[Interval] = []
         for name, s, e in by_line.get(OPS, []):
             iv = _clip(s, e, win)
             if iv is None:
                 continue
             i = bisect.bisect_right(starts, iv[0]) - 1
             mod = _module_name(clipped[i][0]) if i >= 0 else "?"
-            ops_ns[f"{mod}/{_short_op(name)}"] += iv[1] - iv[0]
+            op = _short_op(name)
+            ops_ns[f"{mod}/{op}"] += iv[1] - iv[0]
+            if collective and collective.search(op):
+                coll.append(iv)
+        coll_ns.append(sum(e - s for s, e in union(coll)))
+        coll_calls += len(coll)
     if not chips:
         raise ValueError("no TPU device plane with XLA Modules in the trace")
     host = _host_events(planes, window_span, win)
@@ -175,13 +189,15 @@ def reduce_planes(planes, window_span: str,
         top_ops=[[n, t / chips / 1e9] for n, t in
                  sorted(ops_ns.items(), key=lambda kv: -kv[1])[:TOP]],
         idle_gaps=[[_name_gap(g, host), (g[1] - g[0]) / 1e9] for g in idle],
-        lines=lines)
+        lines=lines,
+        collective_s=sum(coll_ns) / chips / 1e9 if collective else None,
+        collective_calls=coll_calls // chips if collective else None)
 
 
-def reduce_trace(path: str, window_span: str,
-                 kernel_pattern: str) -> TraceSummary:
+def reduce_trace(path: str, window_span: str, kernel_pattern: str,
+                 collective_pattern: Optional[str] = None) -> TraceSummary:
     """Read the ``.xplane.pb`` at ``path`` and reduce it."""
     from jax.profiler import ProfileData
 
     return reduce_planes(ProfileData.from_file(path).planes, window_span,
-                         kernel_pattern)
+                         kernel_pattern, collective_pattern)

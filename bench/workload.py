@@ -1,9 +1,9 @@
 """The one generator every traffic mix goes through.
 
 The configuration fixes the deployment: the stencil, the engine, the
-interior side of the square domain, the steps of one solve (whole
-rounds of ``s_tb``) and the schedule (:func:`solve_params`).  The
-generator is fixed: one client submits whole solves back to back, each
+interior side of the square domain, the steps of one solve and the
+schedule its engine reads (:class:`SolveParams`, read by
+:func:`bench.harness.solve_params`).  The generator is fixed: one client submits whole solves back to back, each
 on the same host domain, uniform over ``[low, high)`` with the frame
 included.  A traffic file (``bench/traffic/<name>.json``) sets ``low``
 and ``high``.  :func:`make_domain` fills the domain by threads over
@@ -15,45 +15,37 @@ from __future__ import annotations
 import dataclasses
 import os
 from concurrent.futures import ThreadPoolExecutor
+from typing import Optional, Tuple
 
 import numpy as np
 
 # fixed row blocks, each with its own child of the seed's SeedSequence
 BLOCKS = 64
 
-
 @dataclasses.dataclass(frozen=True)
 class SolveParams:
-    engine: str       # compile_plan engine name
+    engine: str       # a compile_plan engine name, or "sharded"
     stencil: str      # the program's stencil name
     radius: int
     Y: int
     X: int            # framed domain
     steps: int        # time steps per solve
-    d: int
-    s_tb: int
-    k_on: int
+    d: Optional[int] = None           # compile_plan engines
+    s_tb: Optional[int] = None
+    k_on: Optional[int] = None
+    mesh: Optional[Tuple[int, int]] = None    # the sharded engine
+    k_ici: Optional[int] = None
+
+    @property
+    def chips(self) -> int:
+        """Devices a solve runs on: the mesh's size, else one."""
+        return self.mesh[0] * self.mesh[1] if self.mesh else 1
 
     @property
     def interior_updates(self) -> int:
         """Useful cell-updates of one solve: steps x interior cells."""
         r = self.radius
         return self.steps * (self.Y - 2 * r) * (self.X - 2 * r)
-
-
-def solve_params(config: dict) -> SolveParams:
-    """One solve of the configuration; raises ``ValueError`` for steps
-    that are not whole rounds."""
-    sched = config["schedule"]
-    r = int(config["radius"])
-    side = int(config["interior"]) + 2 * r
-    steps, s_tb = int(config["steps_per_solve"]), int(sched["s_tb"])
-    if steps % s_tb:
-        raise ValueError(f"{steps} steps are not whole rounds of {s_tb}")
-    return SolveParams(
-        engine=config["engine"], stencil=config["stencil"], radius=r,
-        Y=side, X=side, steps=steps, d=int(sched["d"]), s_tb=s_tb,
-        k_on=int(sched["k_on"]))
 
 
 def make_domain(shape, seed: int, low: float = 0.0,

@@ -31,7 +31,8 @@ def _run(cwd, tmp_path, workload="box2d1r.ooc-49152"):
 
 
 @pytest.mark.parametrize("workload", ["box2d1r.ooc-49152",
-                                      "box2d1r.incore-12800"])
+                                      "box2d1r.incore-12800",
+                                      "box2d1r.mesh-2x2"])
 def test_refuses_without_tpu(workload, tmp_path):
     r = _run(ROOT, tmp_path, workload)
     assert r.returncode != 0

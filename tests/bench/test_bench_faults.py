@@ -138,7 +138,7 @@ def test_exchange_left_out_makes_run_incorrect(tiny_root, cell, monkeypatch):
     assert res["correct"] is False, res["checks"]
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CELLS + ["box2d1r.mesh-2x2"])
 def test_control_makes_run_incorrect(tiny_root, cell):
     """The configuration's control in the program's place, at the steps a
     solve of the cell runs, on a tiny domain: ``correct`` comes out

@@ -40,7 +40,8 @@ def test_reference_matches_run_reference(name, side, steps):
 def test_coefficients_are_the_programs():
     from repro import get_stencil
 
-    for name in ("box2d1r-f32", "box2d4r-f32", "box2d1r-f32-incore"):
+    for name in ("box2d1r-f32", "box2d4r-f32", "box2d1r-f32-incore",
+                 "box2d1r-f32-mesh2x2"):
         cfg = _config(name)
         np.testing.assert_array_equal(
             reference.coefficients(cfg),
@@ -79,7 +80,7 @@ def test_frame_cells_changed_counts_each_cell_once():
 
 
 @pytest.mark.parametrize("name,side", [
-    ("box2d1r-f32", 96), ("box2d4r-f32", 136)])
+    ("box2d1r-f32", 96), ("box2d4r-f32", 136), ("box2d1r-f32-mesh2x2", 98)])
 def test_control_fails_the_limit(name, side):
     """The reference computed in the precision below the configuration's
     (``control`` in its file), in the program's place, at the steps a

@@ -108,7 +108,7 @@ class _NoProfiler:
     def stop(self):
         pass
 
-    def reduce(self, kernel_pattern, log):
+    def reduce(self, kernel_pattern, log, collective_pattern=None):
         return trace.TraceSummary(window_s=1.0, busy_s=0.5, kernel_s=0.25,
                                   kernel_calls=1, chips=1, top_ops=[],
                                   idle_gaps=[], lines=[])
@@ -181,7 +181,8 @@ def test_pulls_move_the_plans_d2h_bytes(tiny_root, cell, monkeypatch):
     from repro.core.plan import FusedKernel
     from repro.kernels.dispatch import DispatchPolicy
 
-    from bench.workload import make_domain, solve_params
+    from bench.harness import solve_params
+    from bench.workload import make_domain
 
     lower = importlib.import_module("repro.core.lower")
     pulled = []

@@ -91,3 +91,69 @@ def test_missing_window_span_is_an_error():
     with pytest.raises(ValueError, match="no host span"):
         trace.reduce_trace(str(DATA / f"{TRACES[0]}.xplane.pb"),
                            "no_such_span", KERNELS)
+
+
+# a 1026^2 box2d1r plan sharded 2x2 over a 4-chip TPU v5e host (8
+# steps, k_ici=4: two halo exchanges a solve): the mesh cell's
+# configuration at that size, run by ``harness.run_cell`` with ``trace``
+# on and a 0.5 s window (six solves after the warm-up one), its trace
+# file kept
+SHARDED = DATA / "sharded_box2d1r_1026.xplane.pb"
+SHARD_PROGRAM = r"^jit_global_fn\("
+COLLECTIVES = r"^collective-permute"
+
+
+def _collective_witness(path):
+    """Per chip, the union of the window's collective-permute ops, read
+    from the planes without the reduction's code; mean over chips."""
+    from jax.profiler import ProfileData
+
+    planes = list(ProfileData.from_file(str(path)).planes)
+    win = max(((e.start_ns, e.start_ns + e.duration_ns)
+               for p in planes if p.name == "/host:CPU"
+               for line in p.lines for e in line.events
+               if e.name == "bench_window"), key=lambda iv: iv[1] - iv[0])
+    per_chip = []
+    for p in planes:
+        if not p.name.startswith("/device:TPU:"):
+            continue
+        ivs = sorted((max(e.start_ns, win[0]),
+                      min(e.start_ns + e.duration_ns, win[1]))
+                     for line in p.lines if line.name == "XLA Ops"
+                     for e in line.events
+                     if e.name.lstrip("%").startswith("collective-permute"))
+        total, end = 0, win[0]
+        for s, e in ivs:
+            s = max(s, end)
+            if e > s:
+                total += e - s
+                end = e
+        per_chip.append(total)
+    return sum(per_chip) / len(per_chip) / 1e9, len(per_chip)
+
+
+def test_sharded_trace_collective_time_matches_witness():
+    s = trace.reduce_trace(str(SHARDED), "bench_window", SHARD_PROGRAM,
+                           COLLECTIVES)
+    coll_s, chips = _collective_witness(SHARDED)
+    assert s.chips == chips == 4
+    assert s.kernel_calls >= 1 and s.collective_calls > 0
+    assert s.collective_s == pytest.approx(coll_s, rel=1e-6)
+    assert 0 < s.collective_s < s.busy_s <= s.window_s
+
+
+def test_without_a_collective_pattern_the_summary_is_unchanged():
+    a = trace.reduce_trace(str(SHARDED), "bench_window", SHARD_PROGRAM)
+    b = trace.reduce_trace(str(SHARDED), "bench_window", SHARD_PROGRAM,
+                           COLLECTIVES)
+    assert a.collective_s is None and a.collective_calls is None
+    for f in ("window_s", "busy_s", "kernel_s", "kernel_calls", "chips",
+              "top_ops", "idle_gaps", "lines"):
+        assert getattr(a, f) == getattr(b, f)
+
+
+@pytest.mark.parametrize("name", TRACES)
+def test_one_chip_traces_hold_no_collectives(name):
+    s = trace.reduce_trace(str(DATA / f"{name}.xplane.pb"), "bench_window",
+                           KERNELS, COLLECTIVES)
+    assert s.collective_calls == 0 and s.collective_s == 0.0

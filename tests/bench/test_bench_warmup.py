@@ -3,7 +3,7 @@ every op shape of the cell's solve, so that nothing new is traced in the
 window.  Plans are geometry only: no array is touched."""
 import pytest
 
-from bench import harness, spec, workload
+from bench import harness, spec
 
 
 @pytest.mark.parametrize("cell,chunks", [
@@ -13,7 +13,7 @@ def test_warm_up_covers_every_shape_of_the_solve(cell, chunks):
     from repro import compile_plan, get_stencil
 
     c = spec.load_cell(cell)
-    p = workload.solve_params(c.config)
+    p = harness.solve_params(c.config)
     st = get_stencil(p.stencil)
     plan = compile_plan(p.engine, st, p.Y, p.X, p.steps, p.d, p.s_tb, p.k_on)
     warm, rows = harness.warm_up_plan(p, st, plan)

@@ -54,16 +54,63 @@ def test_plan_work_agrees_with_the_plans_own_accounting(engine, stencil, n,
     side = 256 + 2 * st.radius
     plan = compile_plan(engine, st, side, side, n, d, s_tb, 4)
     ops = [op for op in plan.ops if isinstance(op, FusedKernel)]
-    w = work.plan_work(ops, st.radius, st.flops_per_elem, 4)
-    assert w.cells == sum(op.elements for op in ops)
-    assert w.flops == sum(op.flops for op in ops)
-    assert w.bytes == sum(op.hbm_bytes for op in ops)
-    least = work.least_seconds(ops, st.radius, st.flops_per_elem, 4,
-                               819e9, 30e12)
-    assert least == pytest.approx(sum(
-        work.band_work(op.shape_in[0], op.shape_in[1], st.radius, op.steps,
-                       op.keep_lo[0], op.keep_hi[0], st.flops_per_elem, 4)
-        .least_seconds(819e9, 30e12) for op in ops))
+    calls = work.fused_work(ops, st.radius, st.flops_per_elem, 4)
+    assert len(calls) == len(ops)
+    for op, w in zip(ops, calls):
+        assert (w.cells, w.flops, w.bytes) == (op.elements, op.flops,
+                                               op.hbm_bytes)
+
+
+# (gy0, gx0, h, w, Y, X, radius, steps, cells, elements read + written):
+# counted by hand, step by step
+SHARD_HAND = [
+    # a band whole inside a 40x40 domain, halo 2 on each side: step 1
+    # updates 8x10, step 2 the owned 6x8
+    (10, 10, 10, 12, 40, 40, 1, 2, 8 * 10 + 6 * 8, 10 * 12 + 6 * 8),
+    # a corner band: its top and left halos lie outside the domain, so
+    # those sides stop at the frame (row and column 1): step 1 updates
+    # rows 1-6 x columns 1-8, step 2 rows 1-5 x columns 1-7
+    (-2, -2, 10, 12, 40, 40, 1, 2, 6 * 8 + 5 * 7, 8 * 10 + 6 * 8),
+    # r = 4, one step on a 20x20 band inside the domain
+    (20, 20, 20, 20, 60, 60, 4, 1, 12 * 12, 20 * 20 + 12 * 12),
+]
+
+
+@pytest.mark.parametrize("gy0,gx0,h,w,Y,X,r,m,cells,elems", SHARD_HAND)
+def test_shard_work_matches_hand_count(gy0, gx0, h, w, Y, X, r, m, cells,
+                                       elems):
+    got = work.shard_work(gy0, gx0, h, w, Y, X, r, m, 17, 4)
+    assert (got.cells, got.flops, got.bytes) == (cells, cells * 17,
+                                                 elems * 4)
+
+
+@pytest.mark.parametrize("mesh", [(1, 1), (2, 2), (2, 1)])
+def test_shard_work_against_the_sharded_plan(mesh):
+    """Each round, what the shards write tiles the domain and the last
+    step updates the interior once; the program's masked calls update
+    every cell of their band's centre at each step, at least the useful
+    ones; and a one-chip mesh does no more than the domain's updates."""
+    from repro import get_stencil
+    from repro.core.plan import ShardKernel
+    from repro.core.shard import compile_sharded
+
+    st = get_stencil("box2d1r")
+    side, n, k = 66, 8, 4
+    plan = compile_sharded(st, side, side, n, k, mesh)
+    ops = [op for s in plan.streams for op in s if isinstance(op, ShardKernel)]
+    assert len(ops) == mesh[0] * mesh[1] * n // k
+    calls = [work.shard_work(op.gy0, op.gx0, op.h, op.w, side, side, 1,
+                             op.steps, 17, 4) for op in ops]
+    last = [work.shard_work(op.gy0 + (k - 1), op.gx0 + (k - 1),
+                            op.h - 2 * (k - 1), op.w - 2 * (k - 1), side,
+                            side, 1, 1, 17, 4) for op in ops]
+    interior = (side - 2) ** 2
+    assert sum(w.cells for w in last) == n // k * interior
+    for op, w in zip(ops, calls):
+        assert w.cells <= op.elements
+    useful = sum(w.cells for w in calls)
+    assert useful >= plan.exact_elements
+    assert (useful == plan.exact_elements) == (mesh == (1, 1))
 
 
 def test_peaks_table_has_v5e_and_refuses_unknown_kinds(tmp_path):
