@@ -7,10 +7,16 @@ and holds at least the configuration's ``min_window_solves`` (1 where
 it gives none).  The configuration's engine picks the path from
 :data:`PATHS`:
 
-* an engine of ``compile_plan`` (one chip): ``compile_plan(engine,
-  ...)`` into ``DoubleBufferedExecutor(policy=DispatchPolicy())``;
-  set-up warms every shape up by running one round on the fewest
-  leading rows of the domain whose plan has every op shape of the solve.
+* an engine of ``compile_plan`` (one chip, a 2-D domain):
+  ``compile_plan(engine, ...)`` into
+  ``DoubleBufferedExecutor(policy=DispatchPolicy())``; set-up warms
+  every shape up by running one round on the fewest leading rows of the
+  domain whose plan has every op shape of the solve.
+* ``box_tb`` (one chip, a domain of any rank): ``compile_box_plan`` of
+  the schedule's ``tiles``, ``time_depth`` and ``k_on`` into the same
+  executor; set-up runs one round on the domain cut, along each axis of
+  more than three equal tiles, to its first three, whose plan has every
+  op shape of the solve.
 * ``sharded`` (a mesh of chips): ``compile_sharded(...)`` into
   ``ShardMapExecutor`` on a mesh of the cell's devices.  The solve is
   one program, which the executor traces anew at every solve and loads
@@ -133,7 +139,8 @@ class Context:
 
 def solve_params(config: dict) -> SolveParams:
     """One solve of the configuration, its schedule read by its
-    engine's path; raises ``KeyError`` for an unknown engine or a
+    engine's path.  ``interior`` is a side (a square) or one extent per
+    axis.  Raises ``KeyError`` for an unknown engine or a
     schedule that lacks a key the engine needs, and ``ValueError`` for
     steps that are not whole rounds."""
     engine = config["engine"]
@@ -143,9 +150,12 @@ def solve_params(config: dict) -> SolveParams:
     if missing:
         raise KeyError(f"the schedule lacks {missing} for engine {engine!r}")
     r = int(config["radius"])
-    side = int(config["interior"]) + 2 * r
+    interior = config["interior"]
+    inner = ([int(interior)] * 2 if isinstance(interior, int)
+             else [int(n) for n in interior])
     common = dict(engine=engine, stencil=config["stencil"], radius=r,
-                  Y=side, X=side, steps=int(config["steps_per_solve"]))
+                  shape=tuple(n + 2 * r for n in inner),
+                  steps=int(config["steps_per_solve"]))
     p = path.params(common, sched)
     rounds_of = getattr(p, path.rounds_of)
     if p.steps % rounds_of:
@@ -216,11 +226,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if st.radius != p.radius:
         raise ValueError(f"config radius {p.radius} is not the program's "
                          f"{st.radius} for {p.stencil}")
+    if st.ndim != len(p.shape):
+        raise ValueError(f"config domain {p.shape} is not of the program's "
+                         f"rank {st.ndim} for {p.stencil}")
     with CompileLog() as clog:
         t0 = time.perf_counter()
-        x = make_domain((p.Y, p.X), seed, float(cell.traffic["low"]),
+        x = make_domain(p.shape, seed, float(cell.traffic["low"]),
                         float(cell.traffic["high"]))
-        log(f"setup: domain {p.Y}x{p.X} f32 made in "
+        log(f"setup: domain {_dims(p.shape)} f32 made in "
             f"{time.perf_counter() - t0!r} s")
         plan = path.compile(p, st)
         if control:
@@ -311,17 +324,18 @@ def _check(result: dict, cell: Cell, p: SolveParams, plan, x, out, seed: int,
     place), with the reference; set ``correct``, ``failed`` and, last,
     ``checks`` in ``result``."""
     t0 = time.perf_counter()
-    wins = reference.windows((p.Y, p.X), p.radius, seed=seed,
-                             size=reference.window_size((p.Y, p.X)),
+    wins = reference.windows(p.shape, p.radius, seed=seed,
+                             size=reference.window_size(p.shape),
                              **solve_path(p.engine).seams(plan))
-    numbers = reference.compare(out, x, cell.config, p.steps, wins, control)
+    numbers = reference.compare(out, x, cell.config, p.steps, wins, control,
+                                cell.update())
     limits = cell.config["limits"]
     checks = {k: {"value": v, "limit": limits[k]} for k, v in numbers.items()}
     correct = all(v["value"] <= v["limit"] for v in checks.values())
     log(f"check{f' of the control ({control})' if control else ''}: "
         f"{len(wins)} windows against the "
         f"reference in {time.perf_counter() - t0!r} s: "
-        + ", ".join(label for label, _, _ in wins))
+        + ", ".join(w[0] for w in wins))
     result["correct"] = correct
     result["failed"] = 0 if correct else 1
     result["checks"] = checks
@@ -330,12 +344,20 @@ def _check(result: dict, cell: Cell, p: SolveParams, plan, x, out, seed: int,
     return result
 
 
+def _dims(shape) -> str:
+    return "x".join(str(n) for n in shape)
+
+
 def _exec_note(es) -> str:
     """The executor's kernel notes after a warm-up that executed."""
     if es is None:
         return ""
     return (f", kernel_impl={es.kernel_impl} kernel_compiles="
             f"{es.kernel_compiles} shape_buckets={es.shape_buckets}")
+
+
+def _shape_keys(plan) -> set:
+    return {_shape_key(op) for op in plan.ops}
 
 
 def _shape_key(op) -> tuple:
@@ -363,14 +385,14 @@ def warm_up_plan(p: SolveParams, st, plan):
     from repro import compile_plan
 
     steps = min(p.steps, p.s_tb)
-    need = {_shape_key(op) for op in plan.ops}
+    need = _shape_keys(plan)
     inner = p.Y - 2 * p.radius
     for d in range(min(3, p.d), p.d):
         if inner % p.d:
             break
         rows = inner // p.d * d + 2 * p.radius
         warm = compile_plan(p.engine, st, rows, p.X, steps, d, p.s_tb, p.k_on)
-        if need <= {_shape_key(op) for op in warm.ops}:
+        if need <= _shape_keys(warm):
             return warm, rows
     if steps == p.steps:
         return plan, p.Y
@@ -469,8 +491,8 @@ def _mesh_seams(plan) -> dict:
     """First row and first column of each shard after the first in its
     mesh axis: the places where halos cross between chips."""
     return {"chunk_starts": (),
-            "row_seams": sorted({sh.y0 for sh in plan.shards if sh.y0}),
-            "col_seams": sorted({sh.x0 for sh in plan.shards if sh.x0})}
+            "seams": (sorted({sh.y0 for sh in plan.shards if sh.y0}),
+                      sorted({sh.x0 for sh in plan.shards if sh.x0}))}
 
 
 def _mesh_work(plan, p: SolveParams, flops_per_cell: int) -> List[work.Work]:
@@ -480,6 +502,60 @@ def _mesh_work(plan, p: SolveParams, flops_per_cell: int) -> List[work.Work]:
                             op.steps, flops_per_cell, plan.itemsize)
             for stream in plan.streams for op in stream
             if isinstance(op, ShardKernel)]
+
+
+def _box_params(common: dict, sched: dict) -> SolveParams:
+    tiles = tuple(int(t) for t in sched["tiles"])
+    if len(tiles) != len(common["shape"]) or min(tiles) < 1:
+        raise ValueError(f"tiles {list(tiles)} are not one positive count "
+                         f"per axis of the domain {common['shape']}")
+    return SolveParams(**common, tiles=tiles,
+                       time_depth=int(sched["time_depth"]),
+                       k_on=int(sched["k_on"]))
+
+
+def _compile_box(p: SolveParams, st, shape=None, steps=None, tiles=None):
+    from repro import compile_box_plan
+
+    return compile_box_plan(st, shape or p.shape, steps or p.steps,
+                            tiles or p.tiles, p.time_depth, p.k_on)
+
+
+def warm_up_box_plan(p: SolveParams, st, plan):
+    """One round of the cell's box schedule on the domain cut, along each
+    axis of more than three equal tiles, to its first three: the first,
+    a middle and the last tile of every axis give every op shape of
+    ``plan``.  Where they do not, one round on the whole domain."""
+    steps = min(p.steps, p.time_depth)
+    r = p.radius
+    shape, tiles = list(p.shape), list(p.tiles)
+    for a, (n, t) in enumerate(zip(p.shape, p.tiles)):
+        if t > 3 and (n - 2 * r) % t == 0:
+            shape[a], tiles[a] = (n - 2 * r) // t * 3 + 2 * r, 3
+    warm = _compile_box(p, st, tuple(shape), steps, tuple(tiles))
+    if _shape_keys(plan) <= _shape_keys(warm):
+        return warm
+    if steps == p.steps:
+        return plan
+    return _compile_box(p, st, steps=steps)
+
+
+def _warm_up_box(p: SolveParams, st, plan, ex, x) -> str:
+    warm = warm_up_box_plan(p, st, plan)
+    ex.execute(warm, x[tuple(slice(0, n) for n in warm.shape)])
+    return (f"{warm.op_counts().get('HostCommit', 0)} round(s) on "
+            f"{_dims(warm.shape)} in {warm.d} tile(s)")
+
+
+def _tile_starts(plan) -> dict:
+    """First index of each tile after the first along each axis: the
+    places where one tile's apron reads another's cells."""
+    from repro.core.plan import D2H
+
+    boxes = [op.box for op in plan.ops if isinstance(op, D2H)]
+    return {"chunk_starts": (),
+            "seams": [sorted({b.lo[a] for b in boxes})[1:]
+                      for a in range(len(plan.shape))]}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -504,14 +580,21 @@ PLAN_PATH = SolvePath(
     seams=lambda plan: {"chunk_starts": _chunk_starts(plan)},
     kernel_work=_plan_work)
 
+BOX_PATH = SolvePath(
+    schedule=("tiles", "time_depth", "k_on"), rounds_of="time_depth",
+    params=_box_params, compile=_compile_box, executor=_plan_executor,
+    warm_up=_warm_up_box, seams=_tile_starts, kernel_work=_plan_work)
+
 SHARDED_PATH = SolvePath(
     schedule=("mesh", "k_ici"), rounds_of="k_ici", params=_mesh_params,
     compile=_compile_sharded, executor=_mesh_executor, warm_up=_warm_up_mesh,
     seams=_mesh_seams, kernel_work=_mesh_work)
 
-# engine name -> path: every single-device engine of compile_plan, and
-# the plan sharded over a chip mesh
-PATHS = {**{name: PLAN_PATH for name in ENGINES}, "sharded": SHARDED_PATH}
+# engine name -> path: every single-device engine of compile_plan, but
+# box_tb, which runs its box plan of any rank, and the plan sharded over
+# a chip mesh
+PATHS = {**{name: PLAN_PATH for name in ENGINES}, "box_tb": BOX_PATH,
+         "sharded": SHARDED_PATH}
 
 
 def solve_path(engine: str) -> SolvePath:

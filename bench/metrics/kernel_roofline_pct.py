@@ -1,7 +1,7 @@
 """The kernel calls' share of their roofline (kernels layer).
 
 The least time the chips could take for the calls' useful work
-(:mod:`bench.work`: cells each step must update, the band read once and
+(:mod:`bench.work`: cells each step must update, the box read once and
 written once), each call at ``max(bytes / HBM bandwidth, flops / f32
 rate)``, over the trace's device time of the same calls (the programs
 ``kernel_pattern`` matches, pad and crop included).  On a chip mesh the

@@ -4,10 +4,26 @@ A configuration is the file ``BENCHMARK.json`` names for it; a traffic
 mix is ``bench/traffic/<name>.json``; a per-layer metric is a reader
 ``bench/metrics/<name>.py`` that defines ``read(ctx)``.  Adding any of
 them adds a file and an entry, and edits no file that is there.
+
+A configuration states:
+
+* ``interior``: a side (a square domain) or one extent per axis, of the
+  rank of the program's stencil; the frame is ``radius`` cells more on
+  every side;
+* ``engine`` and its ``schedule`` (:data:`bench.harness.PATHS`): an
+  engine of ``compile_plan`` reads ``d``, ``s_tb`` and ``k_on`` (2-D);
+  ``box_tb`` reads ``tiles`` (one count per axis), ``time_depth`` (steps
+  a round) and ``k_on`` (steps a fused call), for any rank; ``sharded``
+  reads ``mesh`` and ``k_ici``;
+* the reference's update: ``coefficients``, a tap table ``2r+1`` long
+  along each axis of the domain's rank, or instead ``"update":
+  "<name>"``, the function ``update(at)`` of ``bench/updates/<name>.py``
+  (:mod:`bench.reference`), again a file of its own.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -36,12 +52,33 @@ class Cell:
 
     def reader(self, metric: str) -> Callable:
         """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
-        path = self.root / "bench" / "metrics" / f"{metric}.py"
-        mod_spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
-        return mod.read
+        return _module(self.root / "bench" / "metrics" / f"{metric}.py",
+                       "bench_metric").read
+
+    def update(self) -> Optional[Callable]:
+        """The ``update(at)`` function of ``bench/updates/<name>.py`` where
+        the configuration names one (``"update": "<name>"``), else
+        ``None``: the reference's tap sum."""
+        name = self.config.get("update")
+        if name is None:
+            return None
+        return _update_module(self.root / "bench" / "updates" / f"{name}.py",
+                              "bench_update").update
+
+
+def _module(path: Path, prefix: str):
+    """The module of the file ``path``."""
+    name = path.stem.replace(".", "_").replace("-", "_")
+    mod_spec = importlib.util.spec_from_file_location(f"{prefix}_{name}",
+                                                      path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+# an update's module is loaded once a process: the reference's compiled
+# programs are keyed on the function
+_update_module = functools.lru_cache(maxsize=None)(_module)
 
 
 def _metric(entry: dict) -> Metric:

@@ -1,14 +1,16 @@
 """Useful work of the fused stencil steps, counted from shapes alone.
 
-Whatever kernel runs a fused call, and however it pads or tiles the
-band, the call has to update the same cells: at each of its ``m``
-steps, every cell of the band that is not frame and whose neighbours
-are all still valid.  A band of ``H`` rows and full width ``X`` (its
-``r`` frame columns on both sides) loses ``r`` rows per step on each
-side that is not the domain's frame, so step ``s`` updates
-``(H_{s-1} - 2r) * (X - 2r)`` cells, with
-``H_s = H_{s-1} - 2r + (keep_top + keep_bottom) * r``.  The least HBM
-traffic is the band read once and its result written once.
+Whatever kernel runs a fused call, and however it pads or tiles its
+box, the call has to update the same cells: at each of its ``m`` steps,
+every cell of the box that is not frame and whose neighbours are all
+still valid.  A box (:func:`box_work`) loses ``r`` per step on every
+side that is not the domain's frame, and its ``r`` frame cells pass
+through on a side that is; its steps update the trapezoid's boxes, an
+apron's redundant cells included: each step the box's interior, every
+axis ``2r`` short.  A 2-D band of ``H`` rows and full width ``X`` keeps
+its frame columns, so step ``s`` updates ``(H_{s-1} - 2r) * (X - 2r)``
+cells, with ``H_s = H_{s-1} - 2r + (keep_top + keep_bottom) * r``.  The
+least HBM traffic is the box read once and its result written once.
 
 A shard's band on a chip mesh (:func:`shard_work`) holds a halo on all
 four sides and loses ``r`` per step on each, clipped to the domain's
@@ -18,14 +20,15 @@ and the frame holds.
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterable, List
+import math
+from typing import Iterable, List, Sequence
 
 
 @dataclasses.dataclass(frozen=True)
 class Work:
     cells: int    # cell-updates the call must make
     flops: int
-    bytes: int    # band in + band out
+    bytes: int    # box in + box out
 
     def __add__(self, other: "Work") -> "Work":
         return Work(self.cells + other.cells, self.flops + other.flops,
@@ -39,27 +42,32 @@ class Work:
                 >= self.flops / flops_per_s else "compute")
 
 
-def band_work(h_in: int, width: int, radius: int, steps: int,
-              keep_top: bool, keep_bottom: bool, flops_per_cell: int,
-              itemsize: int) -> Work:
-    """Useful work of ``steps`` fused steps on one ``(h_in, width)`` band."""
+def box_work(shape_in: Sequence[int], radius: int, steps: int,
+             keep_lo: Sequence[bool], keep_hi: Sequence[bool],
+             flops_per_cell: int, itemsize: int) -> Work:
+    """Useful work of ``steps`` fused steps on one N-D box of
+    ``shape_in``: the box volumes of the trapezoid summed over its
+    steps, the input box read once and the output box written once."""
     r = radius
-    h, cells = h_in, 0
+    cur, cells = [int(n) for n in shape_in], 0
     for _ in range(steps):
-        if h - 2 * r <= 0:
-            raise ValueError(f"band of {h_in} rows too small for {steps} steps")
-        cells += (h - 2 * r) * (width - 2 * r)
-        h = h - 2 * r + (int(keep_top) + int(keep_bottom)) * r
+        inner = [n - 2 * r for n in cur]
+        if min(inner) <= 0:
+            raise ValueError(f"box {tuple(shape_in)} too small for "
+                             f"{steps} steps")
+        cells += math.prod(inner)
+        cur = [n - 2 * r + (int(kl) + int(kh)) * r
+               for n, kl, kh in zip(cur, keep_lo, keep_hi)]
     return Work(cells=cells, flops=cells * flops_per_cell,
-                bytes=(h_in + h) * width * itemsize)
+                bytes=(math.prod(shape_in) + math.prod(cur)) * itemsize)
 
 
 def fused_work(kernel_ops: Iterable, radius: int, flops_per_cell: int,
                itemsize: int) -> List[Work]:
-    """:func:`band_work` of each of a plan's fused-kernel ops (anything
+    """:func:`box_work` of each of a plan's fused-kernel ops (anything
     with ``shape_in``, ``steps``, ``keep_lo`` and ``keep_hi``)."""
-    return [band_work(op.shape_in[0], op.shape_in[1], radius, op.steps,
-                      op.keep_lo[0], op.keep_hi[0], flops_per_cell, itemsize)
+    return [box_work(op.shape_in, radius, op.steps, op.keep_lo, op.keep_hi,
+                     flops_per_cell, itemsize)
             for op in kernel_ops]
 
 

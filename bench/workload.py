@@ -1,40 +1,61 @@
 """The one generator every traffic mix goes through.
 
 The configuration fixes the deployment: the stencil, the engine, the
-interior side of the square domain, the steps of one solve and the
-schedule its engine reads (:class:`SolveParams`, read by
-:func:`bench.harness.solve_params`).  The generator is fixed: one client submits whole solves back to back, each
+interior of the domain (a side, for a square, or one extent per axis),
+the steps of one solve and the schedule its engine reads
+(:class:`SolveParams`, read by :func:`bench.harness.solve_params`).  The
+generator is fixed: one client submits whole solves back to back, each
 on the same host domain, uniform over ``[low, high)`` with the frame
 included.  A traffic file (``bench/traffic/<name>.json``) sets ``low``
 and ``high``.  :func:`make_domain` fills the domain by threads over
-fixed row blocks, so that the same seed gives the same domain whatever
-the thread count.
+fixed blocks of its first axis, so that the same seed gives the same
+domain whatever the thread count.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Tuple
 
 import numpy as np
 
-# fixed row blocks, each with its own child of the seed's SeedSequence
+# fixed blocks of the first axis, each with its own child of the seed's
+# SeedSequence
 BLOCKS = 64
+
 
 @dataclasses.dataclass(frozen=True)
 class SolveParams:
-    engine: str       # a compile_plan engine name, or "sharded"
+    engine: str       # a compile_plan engine name, "box_tb" or "sharded"
     stencil: str      # the program's stencil name
     radius: int
-    Y: int
-    X: int            # framed domain
+    shape: Tuple[int, ...]    # framed domain, one extent per axis
     steps: int        # time steps per solve
     d: Optional[int] = None           # compile_plan engines
     s_tb: Optional[int] = None
-    k_on: Optional[int] = None
+    k_on: Optional[int] = None        # compile_plan engines and box_tb
     mesh: Optional[Tuple[int, int]] = None    # the sharded engine
     k_ici: Optional[int] = None
+    tiles: Optional[Tuple[int, ...]] = None   # box_tb: tiles per axis
+    time_depth: Optional[int] = None          # box_tb: steps per round
+
+    @property
+    def Y(self) -> int:
+        """Rows of a 2-D framed domain."""
+        return self._plane[0]
+
+    @property
+    def X(self) -> int:
+        """Columns of a 2-D framed domain."""
+        return self._plane[1]
+
+    @property
+    def _plane(self) -> Tuple[int, int]:
+        if len(self.shape) != 2:
+            raise ValueError(f"the domain {self.shape} is not 2-D")
+        return self.shape
 
     @property
     def chips(self) -> int:
@@ -45,7 +66,7 @@ class SolveParams:
     def interior_updates(self) -> int:
         """Useful cell-updates of one solve: steps x interior cells."""
         r = self.radius
-        return self.steps * (self.Y - 2 * r) * (self.X - 2 * r)
+        return self.steps * math.prod(n - 2 * r for n in self.shape)
 
 
 def make_domain(shape, seed: int, low: float = 0.0,

@@ -23,13 +23,14 @@ HAND = [
 
 @pytest.mark.parametrize("r,fpc,h,x,m,kt,kb,cells,nbytes", HAND)
 def test_band_work_matches_hand_count(r, fpc, h, x, m, kt, kb, cells, nbytes):
-    w = work.band_work(h, x, r, m, kt, kb, fpc, 4)
+    """A 2-D band is a box that keeps its frame columns."""
+    w = work.box_work((h, x), r, m, (kt, True), (kb, True), fpc, 4)
     assert (w.cells, w.flops, w.bytes) == (cells, cells * fpc, nbytes)
 
 
 def test_band_too_small_is_refused():
     with pytest.raises(ValueError):
-        work.band_work(8, 30, 4, 1, False, False, 161, 4)
+        work.box_work((8, 30), 4, 1, (False, True), (False, True), 161, 4)
 
 
 def test_least_time_and_bound():
