@@ -37,6 +37,11 @@ compiles the plan once instead:
   small write-back pool as soon as it is ready, under the next chunks'
   kernels; the round's barrier then waits only for what is still in
   flight and for the boxes held back (:func:`_streamed_d2h`).
+* **background domain copy** — the run's host array is filled from the
+  caller's domain in row bands on a thread of its own, under the
+  device's work; reads before the first barrier go to the caller's
+  array, and a write-back or a barrier waits for the rows it needs
+  (:class:`_HostCopy`).
 * **spans** — one :class:`SpanRecorder` per run times every bound op
   (and every phase of the fused step, the barrier and a write-back)
   into :class:`ExecStats` and writes each as a flat profiler host event
@@ -105,6 +110,12 @@ WRITEBACK_THREADS = 2
 # scatter it would hide (a 2-round SO2DR solve on the CPU backend: 3 KB
 # and 88 KB boxes slower streamed, 1 MiB even, 4 MiB faster)
 STREAM_MIN_BYTES = 1 << 20
+# the domain's copy into the run's host array goes in bands of about
+# this many bytes, lowest rows first, on one thread under the device's
+# work: the copy into fresh pages is bound by first-touch page faults
+# (about 0.9 GB/s on a v5e host), so a 9.66 GB domain took 11 s before
+# the first transfer; a domain of one band or less is copied inline
+HOST_COPY_BAND_BYTES = 64 << 20
 
 
 @dataclasses.dataclass
@@ -116,8 +127,11 @@ class ExecStats:
     (:class:`SpanRecorder`): every op class, plus the phases
     ``FusedKernel.{pad,call,crop}``, ``HostCommit.drain``,
     ``D2H.{wait,pull,decode,scatter}`` (``D2H.wait`` on streamed boxes
-    only; their spans are timed on the write-back threads) and
-    ``Execute.validate`` (outside ``wall_s``).  Wall-clock numbers are
+    only; their spans are timed on the write-back threads),
+    ``Execute.validate`` (the domain's check, before ``wall_s``),
+    ``HostCopy.band`` (one band of the domain's copy, on its own thread)
+    and ``HostCopy.wait`` (a write or a barrier waiting for the copy,
+    inside ``wall_s``).  Wall-clock numbers are
     host-observed dispatch+compute time — meaningful for comparing
     executors/kernels on one machine, never for gating CI (the cache/op
     counters are the deterministic part)."""
@@ -428,11 +442,85 @@ class SlotPool:
                     f"{self.leases - self.in_use} released)")
 
 
-def _pull_scatter(host: np.ndarray, sl, rows, codec_name: Optional[str],
+class _HostCopy:
+    """The copy of the caller's domain ``src`` into the run's host array
+    ``dst``, in bands of ``band_rows`` rows along axis 0, lowest first,
+    on one thread, each band a ``HostCopy.band`` span on ``rec`` (a
+    forked recorder).  ``rows`` is the watermark: the rows copied so
+    far.  :meth:`stop` drops the bands not yet started; the running one
+    finishes."""
+
+    def __init__(self, src: np.ndarray, dst: np.ndarray, band_rows: int,
+                 rec: SpanRecorder):
+        self.src, self.dst, self.band_rows, self.rec = src, dst, band_rows, rec
+        self.rows = 0
+        self.error: Optional[BaseException] = None
+        self.stopped = False
+        self.cond = threading.Condition()
+        self.thread = threading.Thread(target=self._run, name="hostcopy",
+                                       daemon=True)
+        self.thread.start()
+
+    def _copy(self, lo: int, hi: int) -> None:
+        self.dst[lo:hi] = self.src[lo:hi]
+
+    def _run(self) -> None:
+        n = self.src.shape[0]
+        try:
+            for lo in range(0, n, self.band_rows):
+                if self.stopped:
+                    return
+                hi = min(lo + self.band_rows, n)
+                with self.rec.span("HostCopy.band"):
+                    self._copy(lo, hi)
+                with self.cond:
+                    self.rows = hi
+                    self.cond.notify_all()
+        except Exception as e:      # raised to the waiters
+            with self.cond:
+                self.error = e
+                self.cond.notify_all()
+
+    def wait(self, row: int, rec: SpanRecorder) -> None:
+        """Return once rows ``[0, row)`` are copied, having waited (if at
+        all) in a ``HostCopy.wait`` span on ``rec``; raise if the copy
+        failed or was stopped short of ``row``."""
+        if self.rows >= row:
+            return
+        with rec.span("HostCopy.wait"):
+            with self.cond:
+                while (self.rows < row and self.error is None
+                       and not self.stopped):
+                    self.cond.wait()
+        if self.error is not None:
+            raise RuntimeError("host domain copy failed") from self.error
+        if self.rows < row:
+            raise RuntimeError("host domain copy stopped")
+
+    def stop(self) -> None:
+        with self.cond:
+            self.stopped = True
+            self.cond.notify_all()
+        self.thread.join()
+
+
+def _start_copy(src: np.ndarray, rec: SpanRecorder,
+                ) -> Tuple[np.ndarray, Optional[_HostCopy]]:
+    """A fresh host array for a run on ``src`` and the copy that fills
+    it: in bands on a thread of its own, or inline (no copy returned)
+    where ``src`` holds one band or less."""
+    if src.nbytes <= HOST_COPY_BAND_BYTES:
+        return src.copy(), None
+    host = np.empty_like(src, order="C")
+    band_rows = max(1, HOST_COPY_BAND_BYTES * src.shape[0] // src.nbytes)
+    return host, _HostCopy(src, host, band_rows, rec.fork(-1, -1))
+
+
+def _pull_scatter(rt: "_Runtime", sl, rows, codec_name: Optional[str],
                   rec: SpanRecorder) -> None:
     """Pull one staged box over the link, decode it if a codec carried
-    it, and scatter it into the host array, each phase a span on
-    ``rec``."""
+    it, and scatter it into ``rt``'s host array once the domain's copy
+    has passed its rows, each phase a span on ``rec``."""
     with rec.span("D2H.pull"):
         rows = np.asarray(rows)
     if codec_name is not None:
@@ -440,18 +528,22 @@ def _pull_scatter(host: np.ndarray, sl, rows, codec_name: Optional[str],
         with rec.span("D2H.decode"):
             codec = get_codec(codec_name)
             rows = codec.decode(codec.encode(rows), rows.shape, rows.dtype)
+    copy = rt.copy
+    if copy is not None:
+        # the copy must not land on the box after it
+        copy.wait(sl[0].stop, rec)
     with rec.span("D2H.scatter"):
-        host[sl] = rows
+        rt.host[sl] = rows
 
 
-def _write_back(host: np.ndarray, sl, rows, codec_name: Optional[str],
+def _write_back(rt: "_Runtime", sl, rows, codec_name: Optional[str],
                 rec: SpanRecorder) -> None:
     """A streamed box's write-back, on a pool thread: wait for the box on
     the device (``D2H.wait``), then pull, decode and scatter it.  The
     device array and its pulled copy die with this call."""
     with rec.span("D2H.wait"):
         jax.block_until_ready(rows)
-    _pull_scatter(host, sl, rows, codec_name, rec)
+    _pull_scatter(rt, sl, rows, codec_name, rec)
 
 
 class _Runtime:
@@ -463,16 +555,27 @@ class _Runtime:
     is committed as soon as it is staged, to a pool of
     ``WRITEBACK_THREADS`` threads that write it into the host array
     under the next chunks' kernels; a held box waits for the round's
-    barrier, as does the barrier for every write-back of the round."""
+    barrier, as does the barrier for every write-back of the round.
 
-    __slots__ = ("host", "regs", "bufs", "staged", "staged_sites", "held",
-                 "pool", "writebacks", "wire", "on_commit",
-                 "committed_round", "spans")
+    Domain copy: while ``copy`` (a :class:`_HostCopy`) fills ``host``,
+    host reads go to ``src``, the caller's domain, and a scatter waits
+    for the copy to pass its rows.  Before the first barrier no read
+    sees a row that a write-back has written (:func:`_streamed_d2h`), so
+    the two arrays agree wherever a read looks; the first barrier waits
+    for the whole copy and points ``src`` at ``host``."""
+
+    __slots__ = ("host", "src", "copy", "regs", "bufs", "staged",
+                 "staged_sites", "held", "pool", "writebacks", "wire",
+                 "on_commit", "committed_round", "spans")
 
     def __init__(self, host: np.ndarray, n_regs: int, n_bufs: int,
                  regs: Optional[List] = None, bufs: Optional[List] = None,
-                 spans: Optional[SpanRecorder] = None):
+                 spans: Optional[SpanRecorder] = None,
+                 src: Optional[np.ndarray] = None,
+                 copy: Optional[_HostCopy] = None):
         self.host = host
+        self.src = src if copy is not None else host
+        self.copy = copy
         self.spans = spans if spans is not None else SpanRecorder()
         # recovery hooks: the newest round whose barrier fully drained
         # (-1 = none), and an optional per-round checkpoint callback
@@ -520,33 +623,41 @@ class _Runtime:
                         WRITEBACK_THREADS, thread_name_prefix="writeback")
                 rec = sp.fork(rnd, chunk)
                 self.writebacks.append((self.pool.submit(
-                    _write_back, self.host, sl, rows, codec_name, rec), rec))
+                    _write_back, self, sl, rows, codec_name, rec), rec))
             else:
                 sp.at(rnd, chunk)
-                _pull_scatter(self.host, sl, rows, codec_name, sp)
+                _pull_scatter(self, sl, rows, codec_name, sp)
         self.staged.clear()
         self.staged_sites.clear()
 
     def barrier(self) -> None:
-        """Write back every box not yet in the host array.
-        ``HostCommit.drain`` waits, on this thread, for the held boxes on
-        the device and for the write-backs in flight (raising the first
-        one's exception); the held boxes are then committed here."""
-        if not self.held and not self.writebacks:
-            return
-        for box, site in self.held:
-            self.staged.append(box)
-            self.staged_sites.append(site)
-        self.held.clear()
-        with self.spans.span("HostCommit.drain"):
-            for _, rows, _ in self.staged:
-                jax.block_until_ready(rows)
-            done, self.writebacks = self.writebacks, []
-            futures.wait([f for f, _ in done])
-            for _, rec in done:
-                self.spans.absorb(rec)
-            for f, _ in done:
-                f.result()
+        """Write back every box not yet in the host array, and complete
+        the domain's copy.  ``HostCommit.drain`` waits, on this thread,
+        for the held boxes on the device and for the write-backs in
+        flight (raising the first one's exception); ``HostCopy.wait``
+        then waits for the rest of the copy, and the held boxes are
+        committed here."""
+        if self.held or self.writebacks:
+            for box, site in self.held:
+                self.staged.append(box)
+                self.staged_sites.append(site)
+            self.held.clear()
+            with self.spans.span("HostCommit.drain"):
+                for _, rows, _ in self.staged:
+                    jax.block_until_ready(rows)
+                done, self.writebacks = self.writebacks, []
+                futures.wait([f for f, _ in done])
+                for _, rec in done:
+                    self.spans.absorb(rec)
+                for f, _ in done:
+                    f.result()
+        copy = self.copy
+        if copy is not None:
+            copy.wait(self.host.shape[0], self.spans)
+            copy.thread.join()
+            self.spans.absorb(copy.rec)
+            self.copy = None
+            self.src = self.host
         self.commit()
 
     def commit_round(self, rnd: int) -> None:
@@ -560,9 +671,14 @@ class _Runtime:
             self.on_commit(rnd, self.host)
 
     def close(self) -> None:
-        """Stop the write-back pool (write-backs not yet started are
-        cancelled, running ones finish) and drop every staged box, so
-        no thread and no device buffer outlives the run."""
+        """Stop the domain's copy (bands not yet started are dropped; a
+        write-back waiting for one raises) and the write-back pool
+        (write-backs not yet started are cancelled, running ones finish)
+        and drop every staged box, so no thread and no device buffer
+        outlives the run."""
+        if self.copy is not None:
+            self.copy.stop()
+            self.copy = None
         if self.pool is not None:
             self.pool.shutdown(wait=True, cancel_futures=True)
             self.pool = None
@@ -641,21 +757,25 @@ class CompiledPlan:
         storage from ``slot_pool`` when given (release it back with
         :meth:`release_runtime` when the run retires).  The run's spans
         go to ``spans`` (a fresh :class:`SpanRecorder` by default),
-        starting with the domain's copy, ``Execute.validate``."""
+        starting with ``Execute.validate``: the domain's check, and the
+        start of its copy into the run's host array (:func:`_start_copy`),
+        which the run's first writes and first barrier wait for."""
         spans = spans if spans is not None else SpanRecorder()
         with spans.span("Execute.validate"):
-            host = validate_domain(self.plan, x)
+            check_domain(self.plan, x)
+            src = np.asarray(x)
+            host, copy = _start_copy(src, spans)
         regs = bufs = None
         if slot_pool is not None:
             regs, bufs = slot_pool.acquire(self.n_reg_slots, self.n_buf_slots)
         return _Runtime(host, self.n_reg_slots, self.n_buf_slots, regs, bufs,
-                        spans)
+                        spans, src, copy)
 
     @staticmethod
     def release_runtime(rt: _Runtime,
                         slot_pool: Optional[SlotPool]) -> None:
-        """Retire a run: stop its write-back pool, then give its slot
-        storage back to ``slot_pool``."""
+        """Retire a run: stop its domain copy and write-back pool, then
+        give its slot storage back to ``slot_pool``."""
         rt.close()
         if slot_pool is not None:
             slot_pool.release(rt.regs, rt.bufs)
@@ -682,8 +802,11 @@ class CompiledPlan:
         every round's barrier drains — the checkpoint hook.  A streamed
         D2H box is written back on a pool thread under the next chunks'
         kernels; a write-back's exception is raised at the next barrier.
-        Leased slot storage is released and the write-back pool stopped
-        on *every* exit path (faulted runs do not leak pool occupancy or
+        The domain's copy into the returned array runs on a thread of
+        its own under the device's work; the first barrier (the final
+        one, where the plan has no other) waits for it.  Leased slot
+        storage is released and the copy and write-back pool stopped on
+        *every* exit path (faulted runs do not leak pool occupancy or
         threads)."""
         rt = self.runtime(x, slot_pool)
         rt.on_commit = on_commit
@@ -1036,7 +1159,7 @@ def lower(plan: ExecutionPlan, policy=None, fused_step=None,
                     sl = op.box.slices()
 
                     def run(rt, _s=slot, _sl=sl, _c=codec):
-                        rows = rt.host[_sl]
+                        rows = rt.src[_sl]
                         rt.wire[_s] = (jnp.asarray(_c.encode(rows)),
                                        rows.shape, rows.dtype)
 
@@ -1072,7 +1195,7 @@ def lower(plan: ExecutionPlan, policy=None, fused_step=None,
                 sl = op.box.slices()
 
                 def run(rt, _s=slot, _sl=sl):
-                    rt.regs[_s] = jnp.asarray(rt.host[_sl])
+                    rt.regs[_s] = jnp.asarray(rt.src[_sl])
 
                 emit(key, "H2D", run)
         elif isinstance(op, BufferWrite):

@@ -189,6 +189,8 @@ def test_exec_stats_op_counts_match_plan():
             == es.op_counts["D2H.scatter"] == counts["D2H"])
     assert es.op_counts["HostCommit.drain"] == counts["HostCommit"]
     assert es.op_counts["Execute.validate"] == 1
+    # a domain of one band or less is copied inline: no copy thread
+    assert "HostCopy.band" not in es.op_counts
     assert set(es.op_wall_s) == set(es.op_counts)
     assert all(t >= 0.0 for t in es.op_wall_s.values())
     assert es.executor == "double_buffered"

@@ -1,8 +1,9 @@
 """The executor's spans (:class:`repro.core.lower.SpanRecorder`): each
-bound op and each phase of the fused step, of the barrier and of a
-streamed write-back is one profiler host event carrying ``run``,
-``round`` and its site, flat on its thread, and its host seconds and
-count land in ``ExecStats`` under the same name.
+bound op and each phase of the fused step, of the barrier, of a
+streamed write-back and of the domain's banded copy is one profiler
+host event carrying ``run``, ``round`` and its site, flat on its thread,
+and its host seconds and count land in ``ExecStats`` under the same
+name.
 
 The solves are traced with JAX's own profiler on the CPU and read back
 from the ``.xplane.pb`` it writes, as the benchmark reads a chip's."""
@@ -24,14 +25,25 @@ from repro.serve.scheduler import ScheduledJob, run_interleaved
 from _subproc import run_fake_device_subprocess
 
 BARRIER = ("HostCommit.drain", "D2H.pull", "D2H.scatter")
+# the domain's copy goes in bands of this many bytes: a few rows each
+BAND_BYTES = 1024
 
 
 @pytest.fixture(autouse=True)
 def stream_small_boxes(monkeypatch):
-    """The solves here are small: boxes of any size may stream, so the
-    write-back threads' spans are made."""
-    monkeypatch.setattr(importlib.import_module("repro.core.lower"),
-                        "STREAM_MIN_BYTES", 0)
+    """The solves here are small: boxes of any size may stream, and the
+    domain is copied in several bands, so the write-back threads' and
+    the copy thread's spans are made."""
+    lower_mod = importlib.import_module("repro.core.lower")
+    monkeypatch.setattr(lower_mod, "STREAM_MIN_BYTES", 0)
+    monkeypatch.setattr(lower_mod, "HOST_COPY_BAND_BYTES", BAND_BYTES)
+
+
+def _bands(plan):
+    """The bands of the domain's copy for a solve of ``plan``."""
+    row_bytes = plan.itemsize * int(np.prod(plan.shape[1:]))
+    rows = max(1, BAND_BYTES // row_bytes)
+    return -(-plan.shape[0] // rows)
 
 
 def _solve(Y=62, X=40, n=8, d=3, k_off=4, k_on=2, seed=0):
@@ -87,10 +99,13 @@ def _assert_flat(spans):
 
 def _expected(plan, site="chunk"):
     """The leaf spans a solve of ``plan`` makes, by ``(name, round,
-    site)``, pad and crop aside: a streamed D2H box waits for the device
-    in a ``D2H.wait`` of its own, a held one in the barrier's drain."""
+    site)``, pad and crop and ``HostCopy.wait`` aside: a streamed D2H box
+    waits for the device in a ``D2H.wait`` of its own, a held one in the
+    barrier's drain; the domain is copied in ``HostCopy.band`` spans, at
+    no site."""
     streamed = _streamed_d2h(plan.ops)
-    exp = Counter({("Execute.validate", -1, -1): 1})
+    exp = Counter({("Execute.validate", -1, -1): 1,
+                   ("HostCopy.band", -1, -1): _bands(plan)})
     for op in plan.ops:
         name = type(op).__name__
         if name == "HostCommit":
@@ -116,7 +131,20 @@ def _on_issuing_thread(spans):
 
 def _found(spans, site="chunk"):
     return Counter((n, s["round"], s[site]) for n, _, _, s in spans
-                   if n not in ("FusedKernel.pad", "FusedKernel.crop"))
+                   if n not in ("FusedKernel.pad", "FusedKernel.crop",
+                                "HostCopy.wait"))
+
+
+def _check_copy_waits(spans, plan):
+    """A ``HostCopy.wait`` is made only where a write or a barrier waited
+    for the copy: at most once at a D2H box's site or at a barrier's."""
+    sites = Counter((op.round, op.chunk) for op in plan.ops
+                    if type(op).__name__ == "D2H")
+    sites.update((op.round, -1) for op in plan.ops
+                 if type(op).__name__ == "HostCommit")
+    waits = Counter((s["round"], s["chunk"]) for n, _, _, s in spans
+                    if n == "HostCopy.wait")
+    assert not waits - sites, (waits, sites)
 
 
 def _check_padding(spans):
@@ -142,6 +170,9 @@ def _check_stats(es, plan):
     assert es.op_counts.get("D2H.wait", 0) == n_streamed
     assert es.op_counts["HostCommit.drain"] == counts["HostCommit"]
     assert es.op_counts["Execute.validate"] == 1
+    assert es.op_counts["HostCopy.band"] == _bands(plan)
+    assert es.op_counts.get("HostCopy.wait", 0) \
+        <= counts["D2H"] + counts["HostCommit"]
     if not n_streamed:
         # every box is written back in the barrier, on the issuing thread
         assert sum(es.op_wall_s[k] for k in BARRIER) \
@@ -158,6 +189,7 @@ def test_double_buffered_solve_spans(tmp_path):
     _assert_flat(spans)
     assert len({s["run"] for _, _, _, s in spans}) == 1
     assert _found(spans) == _expected(plan)
+    _check_copy_waits(spans, plan)
     _check_padding(spans)
     # the op classes with phases are counted, but make no span of their own
     traced = ex.exec_stats
@@ -170,20 +202,26 @@ def test_double_buffered_solve_spans(tmp_path):
     streamed = len(_streamed_d2h(plan.ops))
     assert streamed == 2 * counts["HostCommit"]
     issuing = Counter(n for n, *_ in _on_issuing_thread(spans))
-    assert "D2H.wait" not in issuing
+    assert "D2H.wait" not in issuing and "HostCopy.band" not in issuing
     assert issuing["D2H.pull"] == counts["D2H"] - streamed
+    assert issuing["Execute.validate"] == 1
 
     got, _ = ex.execute(plan, x)       # no profiler running
     np.testing.assert_array_equal(got, want)
     _check_stats(ex.exec_stats, plan)
-    assert ex.exec_stats.op_counts == traced.op_counts
+    # how often a write waits for the copy is a matter of timing
+    untraced = dict(ex.exec_stats.op_counts)
+    untraced.pop("HostCopy.wait", None)
+    assert untraced == {k: v for k, v in traced.op_counts.items()
+                        if k != "HostCopy.wait"}
     assert ex.exec_stats.op_wall_s["HostCommit"] <= ex.exec_stats.wall_s
 
 
 def test_held_boxes_write_back_inside_the_barrier(tmp_path):
     """``naive_tb`` re-reads each box's rows for the next chunk, so every
     box is held for the barrier, which pulls and scatters them on the
-    issuing thread, inside its own seconds."""
+    issuing thread, inside its own seconds; only the domain's copy runs
+    on another thread."""
     plan = compile_plan("naive_tb", get_stencil("box2d1r"), 62, 40, 8, 3,
                         4, 2)
     x = np.random.default_rng(4).random((62, 40), dtype=np.float32)
@@ -192,8 +230,10 @@ def test_held_boxes_write_back_inside_the_barrier(tmp_path):
     ex.execute(plan, x)
     spans = _traced(tmp_path, lambda: ex.execute(plan, x))
     _assert_flat(spans)
-    assert _on_issuing_thread(spans) == spans
+    assert _on_issuing_thread(spans) == [
+        sp for sp in spans if sp[0] != "HostCopy.band"]
     assert _found(spans) == _expected(plan)
+    _check_copy_waits(spans, plan)
     _check_stats(ex.exec_stats, plan)
 
 
@@ -211,6 +251,7 @@ def test_interleaved_jobs_spans(tmp_path):
         assert len({s["run"] for _, _, _, s in mine}) == 1
         runs |= {s["run"] for _, _, _, s in mine}
         assert _found(mine) == _expected(plan)
+        _check_copy_waits(mine, plan)
         _check_padding(mine)
     assert len(runs) == 2
     assert {s["job"] for _, _, _, s in spans} == {10, 11}
