@@ -1,20 +1,29 @@
 """Pluggable executors over :class:`repro.core.plan.ExecutionPlan`.
 
-Three interpreters of the same op schedule:
-
-* :class:`EagerExecutor` — walks ops in plan order; reproduces the
-  pre-refactor engine behavior bit-for-bit against the oracle.
+* :class:`EagerExecutor` — one stage program at a time, in plan order.
 * :class:`DoubleBufferedExecutor` — software-pipelined: chunk ``i+1``'s
   H2D is issued while chunk ``i``'s kernels/D2H are still in flight
   (JAX async dispatch carries the overlap; the issuing thread blocks
   only at a ``HostCommit`` barrier, and a streamed D2H box waits for
   the device on a write-back thread).  This is the paper's
-  multi-stream overlap (Sec. II, N_strm = 3), previously impossible
-  with inline engine loops.
+  multi-stream overlap (Sec. II, N_strm = 3).
 * :class:`DryRunExecutor` — walks no device work at all and returns the
   plan-derived :class:`TransferStats`; the autotuner costs the whole
   configuration sweep with it.  It also costs multi-device
   :class:`~repro.core.plan.ShardedPlan` schedules with zero devices.
+
+The two device executors differ only in that prefetch, and their
+results are bitwise identical.  Both lower a plan once
+(:func:`repro.core.lower.lower`: per-(round, chunk) stage programs of
+pre-bound closures, FusedKernel ops resolved through the kernel-dispatch
+registry in :mod:`repro.kernels.dispatch`, band heights padded to
+per-plan shape buckets so chunks and rounds share one compiled kernel
+signature) and run it through the one stage walker,
+:func:`repro.core.lower.walk_stages`, which the service's interleaved
+batches share.  An :class:`~repro.core.lower.ExecStats` with per-span
+wall clock (every op class and the phases of the fused step and the
+barrier, each also a profiler span) and compilation-cache counters
+lands on ``executor.exec_stats`` after every run.
 
 Sharded plans (:mod:`repro.core.shard`) add two more:
 
@@ -25,18 +34,6 @@ Sharded plans (:mod:`repro.core.shard`) add two more:
 * :class:`ShardMapExecutor` — dispatches the plan to the real
   ``shard_map``/``ppermute`` backend in :mod:`repro.core.distributed`.
 
-The device executors run plans through the lowering layer by default
-(:func:`repro.core.lower.lower`): ops become per-(round, chunk) stage
-programs of pre-bound closures (no per-op ``isinstance`` dispatch),
-FusedKernel ops resolve through the kernel-dispatch registry
-(:mod:`repro.kernels.dispatch`), band heights are padded to per-plan
-shape buckets so chunks/rounds share one compiled kernel signature, and
-an :class:`~repro.core.lower.ExecStats` with per-span wall clock (every
-op class and the phases of the fused step and the barrier, each also a
-profiler span) and compilation-cache counters lands on
-``executor.exec_stats`` after every run.  ``lowered=False`` falls back to the original op-at-a-time
-interpreter (:class:`_DeviceState`) — results are bitwise identical.
-
 All executors return ``(host_array | None, TransferStats)`` where the
 stats always come from :meth:`ExecutionPlan.stats` — accounting is a
 property of the *plan*, not of how it was executed.
@@ -44,19 +41,13 @@ property of the *plan*, not of how it was executed.
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .compress import get_codec
-from .lower import ExecStats, KernelCache, lower, lower_sharded, validate_domain
-from .plan import (
-    BufferRead, BufferWrite, Compress, D2H, Decompress, ExecutionPlan,
-    FusedKernel, H2D, HostCommit, ShardedPlan, TransferStats,
-)
-from .reference import multi_step_band, multi_step_box
+from .lower import ExecStats, KernelCache, lower, lower_sharded
+from .plan import ExecutionPlan, TransferStats
 
 __all__ = [
     "EagerExecutor", "DoubleBufferedExecutor", "DryRunExecutor",
@@ -67,139 +58,6 @@ __all__ = [
 # fused-step implementation signature:
 #   fn(band, stencil_name, steps, keep_top, keep_bottom) -> band
 FusedStep = Callable[..., jnp.ndarray]
-
-
-class _StagedWrite:
-    """One staged D2H.
-
-    ``rows`` stays an async device handle until the HostCommit barrier —
-    also for compressed transfers: the codec's encode/decode round trip
-    runs at commit time (the first point the bytes are forced anyway), so
-    compression never adds a per-chunk sync and the double-buffered
-    overlap is preserved.  ``pending`` is True only between a d2h-side
-    Compress and its Decompress; committing a pending entry is a plan
-    bug."""
-
-    __slots__ = ("box", "rows", "codec", "pending")
-
-    def __init__(self, box, rows, codec=None, pending=False):
-        self.box = box            # destination host Box
-        self.rows = rows          # async jnp handle (or np box payload)
-        self.codec = codec        # codec name; round trip runs at commit
-        self.pending = pending
-
-
-class _DeviceState:
-    """Register/buffer/staging state for the legacy op-at-a-time path.
-
-    Codec ops run for real: the ``Compress``/``Decompress`` pairs the
-    rewrite pass emits encode the transferred rows into an actual byte
-    payload and decode them on the far side (this container is CPU, so
-    the codec's device half runs in NumPy).  H2D encodes eagerly at the
-    Compress op (a pure host-side read; the ``jnp.asarray`` hop carries
-    the encoded bytes) and decodes at the Decompress op; the D2H round
-    trip is recorded at the Decompress op but physically runs at the
-    HostCommit barrier — the first point the device bytes are forced
-    anyway — so compression never introduces a per-chunk sync.  Lossless
-    codecs therefore round-trip bit-exactly through real encoded bytes;
-    accounting still comes from the plan.
-
-    The ``identity`` codec is fast-pathed: its encode/decode is a pure
-    byte copy, so the round trip is skipped entirely — the H2D/D2H is
-    already the copy — while wire-byte accounting (plan-derived) is
-    untouched."""
-
-    def __init__(self, host: np.ndarray, fused_step: Optional[FusedStep]):
-        self.host = host
-        self.fused_step = fused_step   # None = reference (banded path only)
-        self.regs: Dict[str, jnp.ndarray] = {}
-        self.bufs: Dict[str, jnp.ndarray] = {}
-        self.staged: List[_StagedWrite] = []
-        # reg -> (device payload, shape, dtype) between Compress(h2d) and
-        # Decompress(h2d); reg -> codec name between Compress(d2h) and D2H
-        self.h2d_wire: Dict[str, Tuple[jnp.ndarray, tuple, np.dtype]] = {}
-        self.d2h_codec: Dict[str, str] = {}
-
-    def issue_h2d(self, op: H2D) -> None:
-        if op.reg in self.h2d_wire:
-            return   # wire hop already happened at Compress time
-        self.regs[op.reg] = jnp.asarray(self.host[op.box.slices()])
-
-    def _compress(self, op: Compress) -> None:
-        if op.codec == "identity":
-            return   # fast path: the transfer op itself is the pure copy
-        if op.direction == "h2d":
-            rows = self.host[op.box.slices()]
-            payload = get_codec(op.codec).encode(rows)
-            # the wire hop: encoded bytes (not raw rows) go to the device
-            self.h2d_wire[op.reg] = (jnp.asarray(payload), rows.shape, rows.dtype)
-        else:
-            self.d2h_codec[op.reg] = op.codec   # encode happens at the D2H
-
-    def _decompress(self, op: Decompress) -> None:
-        if op.codec == "identity":
-            return
-        if op.direction == "h2d":
-            payload, shape, dtype = self.h2d_wire.pop(op.reg)
-            decoded = get_codec(op.codec).decode(np.asarray(payload), shape, dtype)
-            self.regs[op.reg] = jnp.asarray(decoded)
-        else:
-            entry = self.staged[-1]
-            assert entry.pending and entry.box == op.box, \
-                "Decompress does not match the staged D2H"
-            entry.pending = False   # round trip scheduled; runs at commit
-
-    def issue(self, op) -> None:
-        if isinstance(op, H2D):
-            self.issue_h2d(op)
-        elif isinstance(op, Compress):
-            self._compress(op)
-        elif isinstance(op, Decompress):
-            self._decompress(op)
-        elif isinstance(op, BufferWrite):
-            self.bufs[op.buf] = self.regs[op.reg][op.reg_box.slices()]
-        elif isinstance(op, BufferRead):
-            shared = self.bufs.pop(op.buf)
-            self.regs[op.reg] = jnp.concatenate(
-                [shared, self.regs.pop(op.src)], axis=op.axis)
-        elif isinstance(op, FusedKernel):
-            band = self.regs[op.reg]
-            # banded = a classic 2-D row band (full width, frame columns
-            # along): the registered fused-step kernels apply.  Anything
-            # else (3-D tiles, column chunks) runs the N-D reference.
-            if len(op.shape_in) == 2 and op.keep_lo[1] and op.keep_hi[1]:
-                fn = self.fused_step or multi_step_band
-                self.regs[op.reg] = fn(
-                    band, op.stencil, op.steps,
-                    keep_top=op.keep_lo[0], keep_bottom=op.keep_hi[0])
-            else:
-                self.regs[op.reg] = multi_step_box(
-                    band, op.stencil, op.steps,
-                    keep_lo=op.keep_lo, keep_hi=op.keep_hi)
-        elif isinstance(op, D2H):
-            band = self.regs.pop(op.reg)   # last use of the register
-            codec = self.d2h_codec.pop(op.reg, None)
-            self.staged.append(_StagedWrite(
-                op.box, rows=band[op.reg_box.slices()],
-                codec=codec, pending=codec is not None))
-        elif isinstance(op, HostCommit):
-            self.commit()
-        else:  # pragma: no cover - planner/executor version skew
-            raise TypeError(f"unknown op {op!r}")
-
-    def commit(self) -> None:
-        for entry in self.staged:
-            assert not entry.pending, \
-                "staged D2H committed before its Decompress"
-            jax.block_until_ready(entry.rows)
-        for entry in self.staged:
-            rows = np.asarray(entry.rows)
-            if entry.codec is not None:
-                # the wire round trip: device-side encode, host-side decode
-                codec = get_codec(entry.codec)
-                rows = codec.decode(codec.encode(rows), rows.shape, rows.dtype)
-            self.host[entry.box.slices()] = rows
-        self.staged.clear()
 
 
 class _LoweredExecutorBase:
@@ -217,10 +75,9 @@ class _LoweredExecutorBase:
     _MEMO_CAP = 64   # FIFO bound on retained (plan -> CompiledPlan) entries
 
     def __init__(self, fused_step: Optional[FusedStep] = None,
-                 policy=None, lowered: bool = True, slot_pool=None):
+                 policy=None, slot_pool=None):
         self.fused_step = fused_step
         self.policy = policy
-        self.lowered = lowered
         # kernel-signature cache shared across execute() calls: re-running
         # a plan (or one with the same shape buckets) is all hits
         self.kernel_cache = KernelCache()
@@ -272,44 +129,24 @@ class _LoweredExecutorBase:
                 ) -> Tuple[np.ndarray, TransferStats]:
         """Run a plan.  ``injector``/``retry``/``on_commit`` thread the
         fault-injection and checkpoint hooks through to
-        :meth:`repro.core.lower.CompiledPlan.execute`; they require the
-        lowered path (the legacy op-at-a-time interpreter has no op
-        sites to consult)."""
-        if self.lowered:
-            host, stats, exec_stats = self._compiled(plan).execute(
-                x, pipeline=self._pipeline, slot_pool=self.slot_pool,
-                injector=injector, retry=retry, on_commit=on_commit)
-            exec_stats.executor = self.name
-            self.exec_stats = exec_stats
-            return host, stats
-        if injector is not None or retry is not None or on_commit is not None:
-            raise ValueError(
-                "fault injection / commit hooks require the lowered "
-                "executor path (lowered=True)")
-        host, stats = self._execute_legacy(plan, x)
-        self.exec_stats = None
+        :meth:`repro.core.lower.CompiledPlan.execute`."""
+        host, stats, exec_stats = self._compiled(plan).execute(
+            x, pipeline=self._pipeline, slot_pool=self.slot_pool,
+            injector=injector, retry=retry, on_commit=on_commit)
+        exec_stats.executor = self.name
+        self.exec_stats = exec_stats
         return host, stats
-
-    def _execute_legacy(self, plan, x):
-        raise NotImplementedError
 
 
 class EagerExecutor(_LoweredExecutorBase):
-    """In-order interpreter: one stage program at a time, plan order."""
+    """In-order: one stage program at a time, plan order."""
 
     name = "eager"
     _pipeline = False
 
-    def _execute_legacy(self, plan, x):
-        state = _DeviceState(validate_domain(plan, x), self.fused_step)
-        for op in plan.ops:
-            state.issue(op)
-        state.commit()   # no-op unless a planner forgot the final barrier
-        return state.host, plan.stats()
-
 
 class DoubleBufferedExecutor(_LoweredExecutorBase):
-    """Software-pipelined interpreter (the paper's multi-stream overlap).
+    """Software-pipelined (the paper's multi-stream overlap).
 
     Walks the plan stage-by-stage (one stage per ``(round, chunk)``).
     Before executing stage ``i``'s kernels it issues every H2D of stage
@@ -323,31 +160,6 @@ class DoubleBufferedExecutor(_LoweredExecutorBase):
 
     name = "double_buffered"
     _pipeline = True
-
-    def _execute_legacy(self, plan, x):
-        state = _DeviceState(validate_domain(plan, x), self.fused_step)
-        stages = plan.stages()
-        prefetched: set = set()
-        for j, (key, ops) in enumerate(stages):
-            if key is None:          # HostCommit barrier
-                for op in ops:
-                    state.issue(op)
-                continue
-            # prefetch the next chunk's H2D — and the host-side Compress
-            # feeding it — before touching this chunk's kernels; stop at
-            # barriers (host rows change there)
-            if j + 1 < len(stages) and stages[j + 1][0] is not None:
-                for nxt in stages[j + 1][1]:
-                    if isinstance(nxt, H2D) or (
-                            isinstance(nxt, Compress) and nxt.direction == "h2d"):
-                        state.issue(nxt)
-                        prefetched.add(id(nxt))
-            for op in ops:
-                if id(op) in prefetched:
-                    continue
-                state.issue(op)
-        state.commit()
-        return state.host, plan.stats()
 
 
 class DryRunExecutor:

@@ -1,12 +1,8 @@
 """Plan lowering: stage programs, slot-bound closures, shape-bucketed cache.
 
-The executors in :mod:`repro.core.executor` used to *interpret* every op
-of an :class:`~repro.core.plan.ExecutionPlan` through a Python
-``isinstance`` chain, with registers/buffers living in name-keyed dicts
-and the fused step re-traced by JAX for every distinct band height (every
-``band:r{rnd}c{i}`` register has its own shape, so a d-chunk, R-round
-plan presented up to ``d*R`` signatures per kernel).  This module
-compiles the plan once instead:
+This module compiles an :class:`~repro.core.plan.ExecutionPlan` once, so
+that running it needs no per-op type dispatch and one kernel signature
+serves every chunk and round of a shape bucket, and runs it:
 
 * **stage programs** — :func:`lower` groups ops into per-``(round,
   chunk)`` stages of *pre-bound closures*: register/buffer names are
@@ -15,6 +11,11 @@ compiles the plan once instead:
   execution loop (:meth:`SpanRecorder.run` walks ``for tag, fn, rnd,
   chunk in stage``; the trailing site pair addresses fault injection and
   labels the op's profiler span).
+* **one stage walker** — :func:`walk_stages` runs a schedule of
+  ``(run, stage)`` entries, in order or with the next stage's transfers
+  prefetched under this stage's kernels: one plan's stages for the
+  executors (:meth:`CompiledPlan.execute`), several plans' stages merged
+  for the service (:func:`repro.serve.scheduler.run_interleaved`).
 * **kernel dispatch** — FusedKernel ops are resolved through the
   registry in :mod:`repro.kernels.dispatch` (reference jnp, Pallas,
   DMA-overlapped Pallas, banded-MXU) exactly once at lowering time.
@@ -62,7 +63,7 @@ import time
 from collections import defaultdict
 from concurrent import futures
 from concurrent.futures import ThreadPoolExecutor
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -76,12 +77,14 @@ from .plan import (
     HostCommit, ShardKernel, ShardLoad, ShardStore, ShardedPlan,
     TransferStats,
 )
+from .recovery import PlanExecutionError, plan_fingerprint
 
 __all__ = [
     "ExecStats", "KernelCache", "BucketRegistry", "SlotPool",
-    "CompiledPlan", "LoweredStage", "SpanRecorder", "lower",
+    "CompiledPlan", "LoweredStage", "PlanRun", "SpanRecorder", "lower",
+    "walk_stages",
     "CompiledShardedPlan", "ShardStage", "lower_sharded",
-    "check_domain", "validate_domain",
+    "check_domain",
 ]
 
 # op-class tags: a bound op's first field; OP_TAGS[tag] names its span
@@ -268,13 +271,6 @@ class SpanRecorder:
             self.wall[k] += v
         for k, v in other.count.items():
             self.count[k] += v
-
-    def exec_stats(self, kernel_op: str, **fields) -> ExecStats:
-        """An :class:`ExecStats` of this run's spans; ``kernel_calls``
-        counts ``kernel_op``."""
-        return ExecStats(op_counts=dict(self.count),
-                         op_wall_s=dict(self.wall),
-                         kernel_calls=self.count.get(kernel_op, 0), **fields)
 
 
 class _Span:
@@ -548,8 +544,7 @@ def _write_back(rt: "_Runtime", sl, rows, codec_name: Optional[str],
 
 class _Runtime:
     """Slot-indexed register/buffer/staging state the bound closures run
-    against (the lowered counterpart of the executors' old name-keyed
-    device state).
+    against.
 
     Write-back: a D2H box that the plan lets stream (:func:`_streamed_d2h`)
     is committed as soon as it is staged, to a pool of
@@ -714,12 +709,6 @@ def check_domain(plan, x: np.ndarray) -> None:
                          f"plan itemsize {plan.itemsize}")
 
 
-def validate_domain(plan: ExecutionPlan, x: np.ndarray) -> np.ndarray:
-    """Check a host domain against the plan geometry; return a mutable copy."""
-    check_domain(plan, x)
-    return np.asarray(x).copy()
-
-
 def _noop(rt) -> None:
     return None
 
@@ -738,12 +727,18 @@ class CompiledPlan:
     cache: KernelCache
     lower_s: float
 
+    kernel_op = "FusedKernel"     # the op class ExecStats.kernel_calls counts
+
+    @property
+    def stage_count(self) -> int:
+        """Chunk stages (barriers not counted)."""
+        return sum(1 for s in self.stages if s.key is not None)
+
     def describe(self) -> dict:
         """Deterministic lowering metrics (no execution): what the CI
         bench-gate records next to the plan's byte accounting."""
-        chunk_stages = sum(1 for s in self.stages if s.key is not None)
         return {
-            "stage_count": chunk_stages,
+            "stage_count": self.stage_count,
             "shape_buckets": self.shape_buckets,
             "kernel_impl": self.kernel_impl,
             "reg_slots": self.n_reg_slots,
@@ -754,12 +749,12 @@ class CompiledPlan:
                 slot_pool: Optional[SlotPool] = None,
                 spans: Optional[SpanRecorder] = None) -> _Runtime:
         """Build the slot-indexed runtime for one run, leasing slot
-        storage from ``slot_pool`` when given (release it back with
-        :meth:`release_runtime` when the run retires).  The run's spans
-        go to ``spans`` (a fresh :class:`SpanRecorder` by default),
-        starting with ``Execute.validate``: the domain's check, and the
-        start of its copy into the run's host array (:func:`_start_copy`),
-        which the run's first writes and first barrier wait for."""
+        storage from ``slot_pool`` when given (:meth:`PlanRun.release`
+        gives it back).  The run's spans go to ``spans`` (a fresh
+        :class:`SpanRecorder` by default), starting with
+        ``Execute.validate``: the domain's check, and the start of its
+        copy into the run's host array (:func:`_start_copy`), which the
+        run's first writes and first barrier wait for."""
         spans = spans if spans is not None else SpanRecorder()
         with spans.span("Execute.validate"):
             check_domain(self.plan, x)
@@ -771,20 +766,11 @@ class CompiledPlan:
         return _Runtime(host, self.n_reg_slots, self.n_buf_slots, regs, bufs,
                         spans, src, copy)
 
-    @staticmethod
-    def release_runtime(rt: _Runtime,
-                        slot_pool: Optional[SlotPool]) -> None:
-        """Retire a run: stop its domain copy and write-back pool, then
-        give its slot storage back to ``slot_pool``."""
-        rt.close()
-        if slot_pool is not None:
-            slot_pool.release(rt.regs, rt.bufs)
-
     def execute(self, x: np.ndarray, pipeline: bool = False,
                 slot_pool: Optional[SlotPool] = None,
                 injector=None, retry=None, on_commit=None,
                 ) -> Tuple[np.ndarray, TransferStats, ExecStats]:
-        """Run the stage programs.
+        """Run the stage programs through :func:`walk_stages`.
 
         ``pipeline=True`` issues the next stage's prefetchable ops (H2D
         and host-side Compress) before the current stage's kernels — the
@@ -804,67 +790,150 @@ class CompiledPlan:
         kernels; a write-back's exception is raised at the next barrier.
         The domain's copy into the returned array runs on a thread of
         its own under the device's work; the first barrier (the final
-        one, where the plan has no other) waits for it.  Leased slot
-        storage is released and the copy and write-back pool stopped on
-        *every* exit path (faulted runs do not leak pool occupancy or
-        threads)."""
-        rt = self.runtime(x, slot_pool)
-        rt.on_commit = on_commit
-        rec = rt.spans
-        hits0, miss0 = self.cache.hits, self.cache.misses
-        f0 = injector.faults_injected if injector is not None else 0
-        r0 = injector.retries if injector is not None else 0
-        perf = time.perf_counter
-        t_run = perf()
+        one, where the plan has no other) waits for it."""
+        run = PlanRun(self, x, injector, retry, on_commit)
+        walk_stages([run], [(run, s) for s in range(len(self.stages))],
+                    pipeline, slot_pool)
+        if run.error is not None:
+            raise run.error from run.error.fault
+        return run.host, self.plan.stats(), run.stats()
 
-        def run(ops: Tuple[BoundOp, ...]) -> None:
-            rec.run(ops, rt, injector, retry)
 
-        stages = self.stages
+class PlanRun:
+    """One plan's run in :func:`walk_stages`: its compiled plan, domain,
+    spans and fault hooks, its runtime while it runs, and what its
+    :class:`ExecStats` is built from (:meth:`stats`).  ``host`` is the
+    result once the run has retired; ``error`` the typed
+    :class:`~repro.core.recovery.PlanExecutionError` of a run a terminal
+    fault ended, named by ``label`` in its message."""
+
+    __slots__ = ("compiled", "x", "injector", "retry", "on_commit", "label",
+                 "rec", "rt", "host", "error", "hits", "misses", "inj0",
+                 "wall_s")
+
+    def __init__(self, compiled, x, injector=None, retry=None,
+                 on_commit=None, label: str = "plan execution",
+                 rec: Optional[SpanRecorder] = None):
+        self.compiled, self.x = compiled, x
+        self.injector, self.retry, self.on_commit = injector, retry, on_commit
+        self.label = label
+        self.rec = rec if rec is not None else SpanRecorder()
+        self.rt = self.host = self.error = None
+        self.hits = self.misses = 0
+        self.inj0 = self._injected()
+        self.wall_s = 0.0
+
+    def _injected(self) -> Tuple[int, int]:
+        inj = self.injector
+        return (0, 0) if inj is None else (inj.faults_injected, inj.retries)
+
+    def run(self, ops: Tuple[BoundOp, ...]) -> None:
+        """Run one stage call's bound ops on the runtime, counting the
+        kernel cache's hits and misses meanwhile to this run."""
+        cache = self.compiled.cache
+        h0, m0 = cache.snapshot()
         try:
-            if not pipeline:
-                for stage in stages:
-                    run(stage.ops)
-            else:
-                n = len(stages)
-                prefetched = [False] * n
-                for j, stage in enumerate(stages):
-                    if stage.key is None:       # HostCommit barrier
-                        run(stage.ops)
-                        continue
-                    # prefetch the next chunk's transfers under this
-                    # chunk's kernels; never across a barrier (host rows
-                    # change there)
-                    if j + 1 < n and stages[j + 1].key is not None:
-                        run(stages[j + 1].prefetch)
-                        prefetched[j + 1] = True
-                    run(stage.rest if prefetched[j] else stage.ops)
-            rt.barrier()  # no-op unless a planner forgot the final barrier
-        except InjectedFault as f:
-            from .recovery import PlanExecutionError, plan_fingerprint
-            raise PlanExecutionError(
-                f"plan execution failed at round={f.round} "
-                f"chunk={f.chunk} op={f.op_class}: {f.kind} "
-                f"(last committed round {rt.committed_round})",
-                fault=f, last_committed_round=rt.committed_round,
-                fingerprint=plan_fingerprint(self.plan)) from f
+            self.rec.run(ops, self.rt, self.injector, self.retry)
         finally:
-            self.release_runtime(rt, slot_pool)
+            h1, m1 = cache.snapshot()
+            self.hits += h1 - h0
+            self.misses += m1 - m0
 
-        stats = rec.exec_stats(
-            "FusedKernel",
-            kernel_impl=self.kernel_impl,
-            shape_buckets=self.shape_buckets,
-            kernel_compiles=self.cache.misses - miss0,
-            kernel_cache_hits=self.cache.hits - hits0,
-            stage_count=sum(1 for s in stages if s.key is not None),
-            lower_s=self.lower_s,
-            wall_s=perf() - t_run,
-            faults_injected=(injector.faults_injected - f0)
-            if injector is not None else 0,
-            retries=(injector.retries - r0) if injector is not None else 0,
-        )
-        return rt.host, self.plan.stats(), stats
+    def release(self, slot_pool: Optional[SlotPool]) -> None:
+        """Stop the runtime's domain copy and write-back pool, and give
+        its slot storage back to ``slot_pool``."""
+        rt, self.rt = self.rt, None
+        if rt is not None:
+            rt.close()
+            if slot_pool is not None:
+                slot_pool.release(rt.regs, rt.bufs)
+
+    def stats(self, **fields) -> ExecStats:
+        """The run's :class:`ExecStats`: its spans, cache counts and
+        seconds, and the injector's faults and retries since the run was
+        made."""
+        c, rec = self.compiled, self.rec
+        f1, r1 = self._injected()
+        return ExecStats(
+            op_counts=dict(rec.count), op_wall_s=dict(rec.wall),
+            kernel_calls=rec.count.get(c.kernel_op, 0),
+            kernel_impl=c.kernel_impl,
+            shape_buckets=c.shape_buckets, kernel_compiles=self.misses,
+            kernel_cache_hits=self.hits, stage_count=c.stage_count,
+            lower_s=c.lower_s, wall_s=self.wall_s,
+            faults_injected=f1 - self.inj0[0], retries=r1 - self.inj0[1],
+            **fields)
+
+
+def walk_stages(runs: Sequence[PlanRun],
+                schedule: Sequence[Tuple[PlanRun, int]], pipeline: bool,
+                slot_pool: Optional[SlotPool] = None) -> None:
+    """Run ``schedule``, entries ``(run, stage index)`` that keep each
+    run's stages in plan order, each against its own run's runtime.
+
+    With ``pipeline``, a chunk stage first issues the next entry's
+    prefetchable ops (H2D and host-side Compress) on *that* entry's run,
+    so they ride under this stage's kernels; a barrier neither
+    prefetches nor is prefetched (host rows change there).  A run's last
+    stage is followed by its final barrier (a no-op unless a planner
+    forgot it); the run then retires: its runtime is released and its
+    ``wall_s`` stamped from the walk's start.
+
+    A run whose op raises :class:`~repro.core.faults.InjectedFault` is
+    isolated: its runtime is released at once, its remaining entries are
+    skipped and ``error`` holds the typed error; the other runs go on.
+    Any other exception propagates.  Every runtime is released on every
+    exit path, so no copy thread, write-back pool or slot lease outlives
+    the walk."""
+    perf = time.perf_counter
+
+    def attempt(run: PlanRun, ops: Tuple[BoundOp, ...]) -> bool:
+        try:
+            run.run(ops)
+            return True
+        except InjectedFault as f:
+            done = run.rt.committed_round
+            run.error = PlanExecutionError(
+                f"{run.label} failed at round={f.round} chunk={f.chunk} "
+                f"op={f.op_class}: {f.kind} (last committed round {done})",
+                fault=f, last_committed_round=done,
+                fingerprint=plan_fingerprint(run.compiled.plan))
+            run.release(slot_pool)
+            run.wall_s = perf() - t0
+            return False
+
+    def retire(run: PlanRun) -> None:
+        run.rt.barrier()
+        run.host = run.rt.host
+        run.release(slot_pool)
+        run.wall_s = perf() - t0
+
+    try:
+        for run in runs:
+            run.rt = run.compiled.runtime(run.x, slot_pool, run.rec)
+            run.rt.on_commit = run.on_commit
+        t0 = perf()
+        n = len(schedule)
+        prefetched = [False] * n
+        for m, (run, s) in enumerate(schedule):
+            if run.rt is None:                  # isolated by a fault
+                continue
+            stages = run.compiled.stages
+            if pipeline and stages[s].key is not None and m + 1 < n:
+                nrun, ns = schedule[m + 1]
+                nxt = nrun.compiled.stages[ns]
+                if nxt.key is not None and nrun.rt is not None:
+                    prefetched[m + 1] = attempt(nrun, nxt.prefetch)
+            ops = stages[s].rest if prefetched[m] else stages[s].ops
+            if run.rt is not None and attempt(run, ops) \
+                    and s == len(stages) - 1:
+                retire(run)
+        for run in runs:                        # runs with no stages
+            if run.rt is not None:
+                retire(run)
+    finally:
+        for run in runs:
+            run.release(slot_pool)
 
 
 class _SlotAllocator:
@@ -1420,9 +1489,15 @@ class CompiledShardedPlan:
     # ShardKernel runs a nested plan, whose own spans are the leaves
     phased: frozenset = _PHASED
 
+    kernel_op = "ShardKernel"
+
+    @property
+    def stage_count(self) -> int:
+        return len(self.stages)
+
     def describe(self) -> dict:
         return {
-            "stage_count": len(self.stages),
+            "stage_count": self.stage_count,
             "shape_buckets": self.shape_buckets,
             "kernel_impl": self.kernel_impl,
             "reg_slots": self.n_slots,
@@ -1452,41 +1527,26 @@ class CompiledShardedPlan:
         the pool and releases it when the nested run retires (also on
         fault paths — the inner executor releases in ``finally``), so
         :meth:`SlotPool.assert_balanced` holds after any exit."""
-        rec = SpanRecorder("rank", self.phased)
-        with rec.span("Execute.validate"):
-            host = validate_domain(self.plan, x)
-        rt = _ShardRuntime(host, self.n_slots, slot_pool=slot_pool,
-                           spans=rec)
-        hits0, miss0 = self.cache.hits, self.cache.misses
-        f0 = injector.faults_injected if injector is not None else 0
-        r0 = injector.retries if injector is not None else 0
-        perf = time.perf_counter
-        t_run = perf()
+        run = PlanRun(self, x, injector, retry,
+                      rec=SpanRecorder("rank", self.phased))
+        with run.rec.span("Execute.validate"):
+            check_domain(self.plan, x)
+            host = np.asarray(x).copy()
+        run.rt = _ShardRuntime(host, self.n_slots, slot_pool=slot_pool,
+                               spans=run.rec)
+        t_run = time.perf_counter()
         try:
             for stage in self.stages:
-                rec.run(stage.ops, rt, injector, retry)
-            rt.commit()
+                run.run(stage.ops)
+            run.rt.commit()
         except InjectedFault as f:
-            from .recovery import PlanExecutionError, plan_fingerprint
             raise PlanExecutionError(
                 f"sharded plan failed at round={f.round} rank={f.chunk} "
                 f"op={f.op_class}: {f.kind}",
                 fault=f, last_committed_round=-1,
                 fingerprint=plan_fingerprint(self.plan)) from f
-        stats = rec.exec_stats(
-            "ShardKernel",
-            kernel_impl=self.kernel_impl,
-            shape_buckets=self.shape_buckets,
-            kernel_compiles=self.cache.misses - miss0,
-            kernel_cache_hits=self.cache.hits - hits0,
-            stage_count=len(self.stages),
-            lower_s=self.lower_s,
-            wall_s=perf() - t_run,
-            faults_injected=(injector.faults_injected - f0)
-            if injector is not None else 0,
-            retries=(injector.retries - r0) if injector is not None else 0,
-        )
-        return rt.host, self.plan.stats(), stats
+        run.wall_s = time.perf_counter() - t_run
+        return host, self.plan.stats(), run.stats()
 
 
 def lower_sharded(plan,

@@ -9,11 +9,12 @@ structure can never express.
 Soundness of the round-robin merge: each job's stages stay in its own
 plan order, so every earlier stage of job *j* (including its HostCommit
 barriers) has executed before any later stage of *j* is issued.  The
-double-buffered prefetch discipline from
-:class:`~repro.core.lower.CompiledPlan` carries over unchanged — a
-stage's prefetchable prefix (H2D + host-side Compress) is issued early
-only when the stage is a chunk stage, never across its own job's
-barrier, and always against its own job's runtime.
+merged schedule runs through the same stage walker as a single plan
+(:func:`repro.core.lower.walk_stages`), so the double-buffered prefetch
+rule carries over unchanged — a stage's prefetchable prefix (H2D +
+host-side Compress) is issued early only when the stage is a chunk
+stage, never across its own job's barrier, and always against its own
+job's runtime.
 
 Admission ordering is deadline-aware shortest-predicted-first: the
 dry-run cost model (:func:`repro.core.autotune.predicted_makespan`)
@@ -23,15 +24,15 @@ of best-effort jobs, and ties break on job id for determinism.
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.analytic import Hardware
 from repro.core.autotune import pipeline_makespan, stage_costs
-from repro.core.faults import InjectedFault
-from repro.core.lower import CompiledPlan, ExecStats, SlotPool, SpanRecorder
+from repro.core.lower import (
+    CompiledPlan, ExecStats, PlanRun, SlotPool, SpanRecorder, walk_stages,
+)
 from repro.core.recovery import PlanExecutionError
 
 __all__ = ["ScheduledJob", "admission_order", "interleave_stages",
@@ -116,15 +117,16 @@ def run_interleaved(jobs: Sequence[ScheduledJob],
     given (admission) order: ``(job, host_out, exec_stats, latency_s,
     fault)``.
 
-    Each job gets its own :class:`~repro.core.lower._Runtime` (slot
-    storage leased from ``slot_pool`` when given); the merged walk
-    applies the double-buffered prefetch rule across the *merged*
-    sequence, so job B's H2D is issued while job A's kernels are still
-    in flight — the cross-job analogue of the paper's N_strm = 3
-    overlap.  Latency is stamped when a job's last stage retires (its
-    final barrier has drained its staged writes).  Each job's ops are
-    timed by its own :class:`~repro.core.lower.SpanRecorder`, whose
-    profiler spans carry ``job`` beside ``run``, ``round`` and ``chunk``.
+    The merged schedule goes through the single-device stage walker
+    (:func:`repro.core.lower.walk_stages`), pipelined: each job gets its
+    own runtime (slot storage leased from ``slot_pool`` when given), and
+    the prefetch rule applies across the *merged* sequence, so job B's
+    H2D is issued while job A's kernels are still in flight — the
+    cross-job analogue of the paper's N_strm = 3 overlap.  Latency is
+    stamped when a job's last stage retires (its final barrier has
+    drained its staged writes).  Each job's ops are timed by its own
+    :class:`~repro.core.lower.SpanRecorder`, whose profiler spans carry
+    ``job`` beside ``run``, ``round`` and ``chunk``.
 
     Graceful degradation: a job whose injector raises a terminal fault
     is *isolated* — its leased slots are released on the spot, its
@@ -132,108 +134,16 @@ def run_interleaved(jobs: Sequence[ScheduledJob],
     ``host_out=None`` and the typed ``fault`` attached — while every
     other job's stage walk continues untouched (property-tested: the
     survivors stay bit-identical to a fault-free run)."""
-    perf = time.perf_counter
-    runtimes = {}
-    recs = {j.job_id: SpanRecorder(job=j.job_id) for j in jobs}
-    try:
-        for job in jobs:
-            runtimes[job.job_id] = job.compiled.runtime(
-                job.x, slot_pool, spans=recs[job.job_id])
-        merged = interleave_stages(jobs)
-        n = len(merged)
-        prefetched = [False] * n
-        snap: Dict[int, Tuple[int, int]] = {}   # job -> (hits, misses) deltas
-        inj0: Dict[int, Tuple[int, int]] = {}   # job -> (faults, retries) at t0
-        for j in jobs:
-            snap[j.job_id] = (0, 0)
-            inj0[j.job_id] = ((j.injector.faults_injected, j.injector.retries)
-                              if j.injector is not None else (0, 0))
-        latency: Dict[int, float] = {}
-        failed: Dict[int, PlanExecutionError] = {}
-        last_stage = {j.job_id: len(j.compiled.stages) - 1 for j in jobs}
-
-        def run(job: ScheduledJob, ops) -> None:
-            rt = runtimes[job.job_id]
-            cache = job.compiled.cache
-            h0, m0 = cache.snapshot()
-            try:
-                recs[job.job_id].run(ops, rt, job.injector, job.retry)
-            finally:
-                h1, m1 = cache.snapshot()
-                dh, dm = snap[job.job_id]
-                snap[job.job_id] = (dh + h1 - h0, dm + m1 - m0)
-
-        def try_run(job: ScheduledJob, ops) -> bool:
-            """Run a job's ops; on a terminal injected fault, isolate the
-            job (slots back to the pool immediately) and record the typed
-            error.  Returns False when the job just died."""
-            try:
-                run(job, ops)
-                return True
-            except InjectedFault as f:
-                rt = runtimes[job.job_id]
-                failed[job.job_id] = PlanExecutionError(
-                    f"job {job.job_id} failed at round={f.round} "
-                    f"chunk={f.chunk} op={f.op_class}: {f.kind}",
-                    fault=f, last_committed_round=rt.committed_round)
-                CompiledPlan.release_runtime(rt, slot_pool)
-                runtimes[job.job_id] = None
-                latency[job.job_id] = perf() - t_start
-                return False
-
-        t_start = perf()
-        for m, (job, s) in enumerate(merged):
-            if job.job_id in failed:
-                continue
-            stage = job.compiled.stages[s]
-            if stage.key is None:           # the job's HostCommit barrier
-                try_run(job, stage.ops)
-            else:
-                # prefetch the next merged entry's transfer prefix (on
-                # *its* job's runtime) under this stage's kernels; a
-                # barrier entry prefetches nothing — its own job's host
-                # rows are about to change
-                if m + 1 < n:
-                    nxt_job, nxt_s = merged[m + 1]
-                    if nxt_job.job_id not in failed:
-                        nxt = nxt_job.compiled.stages[nxt_s]
-                        if nxt.key is not None and try_run(nxt_job,
-                                                           nxt.prefetch):
-                            prefetched[m + 1] = True
-                try_run(job, stage.rest if prefetched[m] else stage.ops)
-            if job.job_id not in failed and s == last_stage[job.job_id]:
-                runtimes[job.job_id].barrier()  # planner-forgot-barrier no-op
-                latency[job.job_id] = perf() - t_start
-
-        out = []
-        for job in jobs:
-            dh, dm = snap[job.job_id]
-            if job.injector is not None:
-                df = job.injector.faults_injected - inj0[job.job_id][0]
-                dr = job.injector.retries - inj0[job.job_id][1]
-            else:
-                df = dr = 0
-            stats = recs[job.job_id].exec_stats(
-                "FusedKernel",
-                executor="pipelined",
-                kernel_impl=job.compiled.kernel_impl,
-                shape_buckets=job.compiled.shape_buckets,
-                kernel_compiles=dm,
-                kernel_cache_hits=dh,
-                stage_count=sum(1 for st in job.compiled.stages
-                                if st.key is not None),
-                lower_s=job.compiled.lower_s,
-                wall_s=latency[job.job_id],
-                faults_injected=df,
-                retries=dr,
-            )
-            fault = failed.get(job.job_id)
-            rt = runtimes[job.job_id]
-            out.append((job, rt.host if fault is None else None, stats,
-                        latency[job.job_id], fault))
-        return out
-    finally:
-        for job in jobs:
-            rt = runtimes.get(job.job_id)
-            if rt is not None:
-                CompiledPlan.release_runtime(rt, slot_pool)
+    runs = {j.job_id: PlanRun(j.compiled, j.x, j.injector, j.retry,
+                              label=f"job {j.job_id}",
+                              rec=SpanRecorder(job=j.job_id))
+            for j in jobs}
+    walk_stages(list(runs.values()),
+                [(runs[j.job_id], s) for j, s in interleave_stages(jobs)],
+                pipeline=True, slot_pool=slot_pool)
+    out = []
+    for job in jobs:
+        run = runs[job.job_id]
+        out.append((job, run.host, run.stats(executor="pipelined"),
+                    run.wall_s, run.error))
+    return out

@@ -38,8 +38,7 @@ def test_heat3d_box_tb_matches_reference_with_temporal_blocking():
     scale = float(jnp.abs(ref).max())
     plan = compile_box_plan(st, shape, n, tiles=(2, 3, 2), time_depth=2)
     assert plan.k_off == 2 and plan.d == 12
-    for ex in (EagerExecutor(), DoubleBufferedExecutor(),
-               EagerExecutor(lowered=False)):
+    for ex in (EagerExecutor(), DoubleBufferedExecutor()):
         out = ex.execute(plan, x)[0]
         assert float(jnp.max(jnp.abs(out - ref))) / scale <= 1e-5
 

@@ -192,12 +192,6 @@ def test_clean_run_with_injector_is_invisible():
     assert ex.exec_stats.retries == 0
 
 
-def test_legacy_executor_path_rejects_hooks():
-    with pytest.raises(ValueError, match="lowered"):
-        EagerExecutor(lowered=False).execute(
-            _plan(), _domain(), injector=FaultPlan([]).injector())
-
-
 # ----------------------------------------------------- slot-lease leaks
 
 

@@ -1,9 +1,9 @@
 """Kernel-backed compiled execution: lowering, dispatch, and the cache.
 
-The lowered executors must (a) be bit-identical to the legacy op-at-a-time
-interpreter for every engine, (b) drive the real Pallas kernels (interpret
-mode) through the dispatch registry and still match the oracle, and
-(c) compile at most one kernel per *shape bucket* — not per chunk x round
+The executors must (a) be bit-identical to each other, to an unbucketed
+lowering and to a one-job interleaved walk for every engine, and lie on
+the oracle, (b) drive the real Pallas kernels (interpret mode) through
+the dispatch registry and still match the oracle, and (c) compile at most one kernel per *shape bucket* — not per chunk x round
 — with the counters to prove it in :class:`repro.core.lower.ExecStats`.
 """
 import numpy as np
@@ -12,13 +12,16 @@ import pytest
 
 from repro.core.compress import CODECS
 from repro.core.executor import DoubleBufferedExecutor, EagerExecutor
+from repro.core.faults import KERNEL_FAULT, FaultPlan, FaultTrigger
 from repro.core.lower import KernelCache, lower
 from repro.core.oocore import ENGINES, compile_plan
+from repro.core.recovery import PlanExecutionError
 from repro.core.reference import run_reference
 from repro.core.stencil import get_stencil
 from repro.kernels.dispatch import (
     DispatchPolicy, KERNEL_IMPLS, modeled_kernel_time, select_kernel,
 )
+from repro.serve.scheduler import ScheduledJob, run_interleaved
 
 RNG = np.random.default_rng(31)
 
@@ -34,21 +37,71 @@ def _plan(engine, st, x, n=4, d=2, k_off=2, k_on=2, codec=None):
                         k_off, k_on, codec=codec)
 
 
-# ------------------------------------------------- lowered vs legacy
+# ------------------------------------------------- executors vs each other
 
 
 @pytest.mark.parametrize("engine", sorted(ENGINES))
-def test_lowered_executors_bitwise_match_legacy(engine):
-    """Lowering is a pure compilation step: slot binding, stage programs,
-    and shape-bucket padding must not change a single bit."""
+def test_executors_bitwise_match_each_other(engine):
+    """Lowering is a pure compilation step and pipelining a pure
+    reordering: the eager and the double-buffered walk, and a lowering
+    without shape buckets, agree to the bit, and all lie on the oracle."""
     st = get_stencil("box2d2r")
     x = _domain(st, rows=48)
     plan = _plan(engine, st, x, n=8, d=4, k_off=4)
-    for cls in (EagerExecutor, DoubleBufferedExecutor):
-        lowered_out, lowered_stats = cls().execute(plan, x)
-        legacy_out, legacy_stats = cls(lowered=False).execute(plan, x)
-        np.testing.assert_array_equal(lowered_out, legacy_out)
-        assert lowered_stats == legacy_stats
+    eager_out, eager_stats = EagerExecutor().execute(plan, x)
+    db_out, db_stats = DoubleBufferedExecutor().execute(plan, x)
+    flat_out, flat_stats = EagerExecutor(
+        policy=DispatchPolicy(bucket=False)).execute(plan, x)
+    np.testing.assert_array_equal(eager_out, db_out)
+    np.testing.assert_array_equal(eager_out, flat_out)
+    assert eager_stats == db_stats == flat_stats
+    ref = np.asarray(run_reference(jnp.asarray(x), st, plan.n))
+    scale = np.abs(ref).max() + 1e-6
+    assert np.abs(eager_out - ref).max() / scale < 1e-5
+
+
+@pytest.mark.parametrize("n", [8, 0])
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_solo_run_is_a_one_job_interleaved_run(engine, n):
+    """One job through the service's interleaved walk is the executor's
+    pipelined solve: the same host bits and the same counters, also for
+    a plan of no steps (most engines' plans then have no stage at all)."""
+    st = get_stencil("box2d2r")
+    x = _domain(st, rows=48)
+    plan = _plan(engine, st, x, n=n, d=4, k_off=4)
+    solo_host, _, solo = lower(plan).execute(x, pipeline=True)
+    job = ScheduledJob(job_id=7, compiled=lower(plan), x=x, predicted_s=0.0)
+    (_, host, stats, latency, fault), = run_interleaved([job])
+    assert fault is None and latency == stats.wall_s
+    np.testing.assert_array_equal(host, solo_host)
+    assert stats.executor == "pipelined"
+    for field in ("op_counts", "kernel_compiles", "kernel_cache_hits",
+                  "stage_count"):
+        assert getattr(stats, field) == getattr(solo, field), field
+
+
+def test_solo_and_interleaved_fault_alike():
+    """A terminal injected fault raises from the solo run and comes back
+    from the interleaved walk as the same typed error."""
+    st = get_stencil("box2d1r")
+    x = _domain(st, rows=48)
+    plan = _plan("so2dr", st, x, n=8, d=4, k_off=4)
+    faults = FaultPlan([FaultTrigger(round=1, chunk=2,
+                                     op_class="FusedKernel",
+                                     kind=KERNEL_FAULT)])
+    with pytest.raises(PlanExecutionError) as err:
+        lower(plan).execute(x, pipeline=True, injector=faults.injector())
+    job = ScheduledJob(job_id=3, compiled=lower(plan), x=x, predicted_s=0.0,
+                       injector=faults.injector())
+    (_, host, stats, _, fault), = run_interleaved([job])
+    assert host is None and isinstance(fault, PlanExecutionError)
+    solo = err.value
+    assert (fault.fault.round, fault.fault.chunk, fault.fault.op_class) \
+        == (solo.fault.round, solo.fault.chunk, solo.fault.op_class) \
+        == (1, 2, "FusedKernel")
+    assert fault.last_committed_round == solo.last_committed_round == 0
+    assert fault.fingerprint == solo.fingerprint
+    assert stats.faults_injected == 1
 
 
 # ------------------------------------------------- kernel-backed execution
@@ -229,10 +282,9 @@ def test_identity_codec_round_trip_is_skipped(monkeypatch):
     monkeypatch.setattr(idc, "encode", boom)
     monkeypatch.setattr(idc, "decode", boom)
     for cls in (EagerExecutor, DoubleBufferedExecutor):
-        for lowered in (True, False):
-            out, stats = cls(lowered=lowered).execute(plan_id, x)
-            np.testing.assert_array_equal(out, base)
-            assert stats.codec_ops == plan_id.op_counts()["Compress"] * 2
+        out, stats = cls().execute(plan_id, x)
+        np.testing.assert_array_equal(out, base)
+        assert stats.codec_ops == plan_id.op_counts()["Compress"] * 2
 
 
 # ------------------------------------------------- dispatch registry
